@@ -22,6 +22,7 @@ the registry backend key, dataflow mode, and mesh placement.
 
 from __future__ import annotations
 
+import os
 import threading
 from typing import Callable, Hashable, NamedTuple
 
@@ -39,18 +40,39 @@ __all__ = [
     "CacheStats",
     "CompileCache",
     "enable_persistent_cache",
+    "persistent_cache_dir",
 ]
 
 
-def enable_persistent_cache(cache_dir: str) -> None:
-    """Point XLA's persistent compilation cache at ``cache_dir``.
+# <checkout>/.jax_cache: src/repro/api/cache.py is four levels below the
+# checkout root.  A fixed path, because the directory is part of what a
+# persistent-cache entry is found by.
+_CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    ),
+    ".jax_cache",
+)
+
+
+def persistent_cache_dir() -> str:
+    """Where :func:`enable_persistent_cache` puts the cache by default:
+    ``$JAX_COMPILATION_CACHE_DIR`` when that is set, else the fixed
+    ``.jax_cache`` directory at the root of the checkout."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or _CHECKOUT_CACHE_DIR
+
+
+def enable_persistent_cache(cache_dir: str | None = None) -> str:
+    """Point XLA's persistent compilation cache at ``cache_dir`` (default
+    :func:`persistent_cache_dir`) and return the directory used.
 
     The in-process :class:`CompileCache` dedupes executables per
     ``(bucket, slots, variant)`` key but dies with the process; wiring
     JAX's persistent cache underneath means a restarted server's *first*
     compile per bucket is a disk hit instead of a cold XLA compile
     (skipped warmup).  Process-wide by necessity — the JAX cache is
-    global — and idempotent; opt in via ``Session(cache_dir=...)``.
+    global — and idempotent.  ``Session(cache_dir=...)`` passes an
+    explicit directory, which overrides the default.
 
     The entry-size/compile-time floors are dropped to 0 so even the small
     CPU-test executables round-trip (JAX's defaults skip sub-second
@@ -61,17 +83,17 @@ def enable_persistent_cache(cache_dir: str) -> None:
     replayed on warm start (``repro.kernels.autotune.lookup`` — the
     planner consults it whenever it builds a fused executor).
     """
-    import os
-
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", str(cache_dir))
+    cache_dir = str(cache_dir) if cache_dir is not None else persistent_cache_dir()
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
     from ..kernels import autotune
 
-    autotune.set_store(os.path.join(str(cache_dir), "autotune.json"))
+    autotune.set_store(os.path.join(cache_dir, "autotune.json"))
+    return cache_dir
 
 
 class Bucket(NamedTuple):

@@ -325,20 +325,23 @@ class Planner:
         return autotune.lookup(bucket, slots).clamp(bucket.nnz_pad)
 
     def build_executor(self, key: tuple[Bucket, int, Any]):
-        """Compile-cache builder: one peel executor per cache key.
+        """Compile-cache builder: one peel executor per cache key,
+        compiled ahead of time for the key's packed shapes — a kernel the
+        device's compiler refuses raises here, and the cache types it as
+        a ``CompileError``.
 
         ``chunk``/``max_iters`` are read from the key, not ``self`` —
         every non-static input that specializes the executable must
         arrive through the variant tuple (see :meth:`cache_variant`).
         ``self.mesh`` is the one closed-over object (unhashable), keyed
         by its ``_mesh_key`` fold."""
-        bucket, _slots, (backend, mode, _mesh_key, chunk, max_iters, fused_sig) = key
+        bucket, slots, (backend, mode, _mesh_key, chunk, max_iters, fused_sig) = key
         fused_config = None
         if fused_sig is not None:
             from ..kernels.autotune import FusedConfig
 
             fused_config = FusedConfig.from_signature(fused_sig)
-        return get_backend(backend).make_executor(
+        exe = get_backend(backend).make_executor(
             window=bucket.window,
             chunk=chunk,
             max_iters=max_iters,
@@ -346,6 +349,10 @@ class Planner:
             mode=mode,
             fused_config=fused_config,
         )
+        exe.compile(
+            n=slots * bucket.n_pad, nnz_pad=slots * bucket.nnz_pad, slots=slots
+        )
+        return exe
 
     def _slot_ids_for(self, batch: PlannedBatch, edge_ranges) -> np.ndarray:
         nnzp_total = batch.slots * batch.bucket.nnz_pad

@@ -9,10 +9,13 @@ choice a first-class, swappable backend axis instead of a constructor
 flag smeared across entry points:
 
 * ``formulation`` — ``coarse`` (row tasks) | ``fine`` (nonzero tasks);
-* ``kernel``      — ``xla`` (fused scatter/gather ops) | ``pallas``
-                    (hand-written TPU kernels, interpret-mode on CPU) |
-                    ``fused`` (persistent Pallas peel megakernel: one
-                    launch per truss level, autotuned per bucket);
+* ``kernel``      — ``xla`` (fused scatter/gather ops; the default on
+                    every platform) | ``pallas`` (hand-written TPU kernels,
+                    interpret-mode off the TPU) | ``fused`` (persistent
+                    Pallas peel megakernel: one launch per truss level,
+                    autotuned per bucket).  Neither Pallas kernel lowers
+                    for a TPU at the windows the auto rule produces, so
+                    both are reached only when forced;
 * ``layout``      — ``contig`` (prefix-sum packed lanes) | ``aligned``
                     (slot-aligned lanes, shardable across a mesh; the
                     only layout whose slot-banded lane geometry the fused
@@ -151,10 +154,17 @@ def available_backends() -> tuple[BackendKey, ...]:
 
 
 def default_kernel() -> str:
-    """Pallas on TPU, XLA everywhere else."""
-    import jax
+    """XLA on every platform.
 
-    return "pallas" if jax.default_backend() == "tpu" else "xla"
+    The Pallas kernels stay off the auto path until they lower for a TPU
+    at the windows the auto rule gives them: Mosaic refuses the fused
+    kernel's 1-D vector gathers and the ``bsearch`` schedule's
+    ``take_along_axis``, and the ``compare`` schedule's (T, W, 128)
+    intermediate overflows scoped VMEM above tile=128, W=128.  Forcing
+    either kernel on a TPU raises a typed ``CompileError`` when the
+    bucket's executor is built (``Planner.build_executor``).
+    """
+    return "xla"
 
 
 def choose_backend(
@@ -177,8 +187,8 @@ def choose_backend(
     (the road-network regime, where the paper measures fine/coarse ≈ 1×),
     otherwise fine.  The Pallas and fused kernels
     implement the fine formulation only, so ``kernel="pallas"`` or
-    ``"fused"`` forces ``fine``.  On the hand-kernel path
-    (``kernel="pallas"``, the TPU default) a *heavily* imbalanced bucket
+    ``"fused"`` forces ``fine``.  On the forced hand-kernel path
+    (``kernel="pallas"``) a *heavily* imbalanced bucket
     (``coarse_imbalance > 8``) is upgraded to the fused megakernel when
     its aligned variant is registered: a heavy degree tail means long
     peel tails with mostly-dead lanes, which is exactly the regime the

@@ -212,7 +212,7 @@ class Session:
         (``BackendKey`` / ``"fine/xla/aligned"``); ``None`` = per-query
         auto rule on the paper's imbalance statistics.
       kernel / layout: defaults for the auto rule
-        (kernel ``None`` = pallas on TPU, xla elsewhere).
+        (kernel ``None`` = xla; see :func:`repro.api.default_kernel`).
       mode: override the backend's update dataflow (``eager``/``owner``).
       max_batch: packed slots per dispatch (batches pad to this, so the
         executable is independent of batch fullness).

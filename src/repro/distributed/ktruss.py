@@ -24,18 +24,34 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core.eager_fine import FineProblem
 
-__all__ = ["SLOT_AXIS", "slot_mesh", "peel_problem_specs", "shard_peel_args"]
+__all__ = [
+    "SLOT_AXIS",
+    "slot_mesh",
+    "peel_problem_specs",
+    "peel_arg_shardings",
+    "shard_peel_args",
+]
 
 SLOT_AXIS = "slots"
 
 
 def slot_mesh(num_devices: int | None = None) -> Mesh:
-    """1-D mesh over the ``"slots"`` axis (all local devices by default)."""
+    """1-D mesh over the ``"slots"`` axis (the first ``num_devices`` local
+    devices; all of them by default).
+
+    The axis is ``Auto``: ``jax.make_mesh`` defaults to explicit axes, under
+    which arguments placed on this mesh outside a mesh context lose their
+    ``"slots"`` partitioning when the peel is traced."""
     devs = jax.devices()
     d = int(num_devices) if num_devices is not None else len(devs)
     if d > len(devs):
         raise ValueError(f"requested {d} devices, have {len(devs)}")
-    return jax.make_mesh((d,), (SLOT_AXIS,))
+    return jax.make_mesh(
+        (d,),
+        (SLOT_AXIS,),
+        axis_types=(jax.sharding.AxisType.Auto,),
+        devices=devs[:d],
+    )
 
 
 def peel_problem_specs() -> list[P]:
@@ -59,6 +75,16 @@ def peel_problem_specs() -> list[P]:
         edge,  # uedge_row(unnzp,)
         rep,  # udeg     (n+1,)
     ]
+
+
+def peel_arg_shardings(mesh: Mesh) -> tuple:
+    """Sharding per peel argument ``(p, slot_ids, k0, single_level, alive0,
+    frozen, frozen_truss)``: slot blocks sharded, vertex metadata
+    replicated.  Shared by :func:`shard_peel_args` (placing arrays) and the
+    executor's ahead-of-time compile (placing shapes)."""
+    slots = NamedSharding(mesh, P(SLOT_AXIS))
+    p = FineProblem(*(NamedSharding(mesh, s) for s in peel_problem_specs()))
+    return (p, slots, slots, slots, slots, slots, slots)
 
 
 def shard_peel_args(
@@ -85,18 +111,5 @@ def shard_peel_args(
             f"mesh size {d} must evenly divide slots={num_slots} "
             f"(and nnz_pad={nnzp}) so each device owns whole slots"
         )
-
-    def put(x, spec):
-        return jax.device_put(x, NamedSharding(mesh, spec))
-
-    p = FineProblem(*(put(x, s) for x, s in zip(p, peel_problem_specs())))
-    edge, slot = P(SLOT_AXIS), P(SLOT_AXIS)
-    return (
-        p,
-        put(slot_ids, edge),
-        put(k0, slot),
-        put(single_level, slot),
-        put(alive0, edge),
-        put(frozen, edge),
-        put(frozen_truss, edge),
-    )
+    args = (p, slot_ids, k0, single_level, alive0, frozen, frozen_truss)
+    return jax.device_put(args, peel_arg_shardings(mesh))

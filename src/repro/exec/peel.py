@@ -42,6 +42,7 @@ __all__ = [
     "PeelState",
     "make_problem_support",
     "init_peel_state",
+    "peel_arg_shapes",
     "build_peel",
     "build_fused_peel",
     "PeelExecutor",
@@ -149,6 +150,32 @@ def init_peel_state(
         total_iters=jnp.int32(0),
         edges_alive=edges0,
     )
+
+
+def peel_arg_shapes(*, n: int, nnz_pad: int, slots: int) -> tuple:
+    """Shapes of one peel call's arguments ``(p, slot_ids, k0,
+    single_level, alive0, frozen, frozen_truss)`` for a packed problem of
+    ``n`` vertices, ``nnz_pad`` directed lanes (twice that undirected) and
+    ``slots`` slots — what ``repro.graphs.pack.pack_problems`` produces for
+    ``slots`` members of one bucket."""
+
+    def shape(length, dtype):
+        return jax.ShapeDtypeStruct((length,), dtype)
+
+    i32, b8 = jnp.int32, jnp.bool_
+    p = FineProblem(
+        rowptr=shape(n + 1, i32),
+        colidx=shape(nnz_pad, i32),
+        edge_row=shape(nnz_pad, i32),
+        deg=shape(n + 1, i32),
+        urowptr=shape(n + 1, i32),
+        ucolidx=shape(2 * nnz_pad, i32),
+        u2d=shape(2 * nnz_pad, i32),
+        uedge_row=shape(2 * nnz_pad, i32),
+        udeg=shape(n + 1, i32),
+    )
+    lanes_i32, lanes_b8 = shape(nnz_pad, i32), shape(nnz_pad, b8)
+    return (p, lanes_i32, shape(slots, i32), shape(slots, b8), lanes_b8, lanes_b8, lanes_i32)
 
 
 def build_peel(
@@ -262,27 +289,20 @@ def build_peel(
 
 
 def build_fused_peel(
-    *,
-    window: int,
-    block: int = 128,
-    schedule: str = "compare",
-    max_iters: int | None = None,
+    level_step: Callable, *, max_iters: int | None = None
 ) -> Callable:
     """Host-driven fused peel: one Pallas megakernel launch per level.
 
     Same signature and bit-identical results as :func:`build_peel`'s
     callable, but the support→prune fixed point of each level runs
-    entirely inside one persistent kernel
-    (``repro.kernels.peel_fused.make_fused_level``), and the host loop
+    entirely inside one persistent kernel — ``level_step``, built by
+    ``repro.kernels.peel_fused.make_fused_level`` — and the host loop
     steps levels — emitting one ``"peel-level"`` span and one
     ``peel_fused_levels`` counter tick per launch so traces show one
     kernel per level.  A fired iteration cap returns the un-done state;
     :meth:`PeelExecutor.peel`'s all-done belt raises the typed
     ``DeviceError`` exactly as on the unfused path.
     """
-    from ..kernels.peel_fused import make_fused_level  # lazy: dep-light
-
-    level_step = make_fused_level(window=window, block=block, schedule=schedule)
 
     def peel(
         p: FineProblem,
@@ -357,16 +377,18 @@ class PeelExecutor:
             if window is None:
                 raise ValueError("window is required for the fused backend")
             from ..kernels.autotune import FusedConfig  # lazy: dep-light
+            from ..kernels.peel_fused import make_fused_level
 
             cfg = fused_config if fused_config is not None else FusedConfig()
             self.fused_config = cfg
             self.support = None
             self.mesh = None
+            self._level_step = make_fused_level(
+                window=window, block=cfg.block, schedule=cfg.schedule
+            )
+            # Late-bound so compile() can swap in the ahead-of-time build.
             self._peel = build_fused_peel(
-                window=window,
-                block=cfg.block,
-                schedule=cfg.schedule,
-                max_iters=max_iters,
+                lambda *args: self._level_step(*args), max_iters=max_iters
             )
             self.dispatches = 0
             return
@@ -385,6 +407,43 @@ class PeelExecutor:
         self.mesh = mesh
         self._peel = build_peel(support, max_iters=max_iters)
         self.dispatches = 0
+
+    def compile(self, *, n: int, nnz_pad: int, slots: int) -> None:
+        """Compile now, for packed problems of ``n`` vertices, ``nnz_pad``
+        edge lanes and ``slots`` slots, so that a program the device's
+        compiler refuses fails here — when the bucket's executor is built —
+        and not at its first dispatch.  Later :meth:`peel` calls with those
+        shapes run the compiled program; other shapes are refused.
+        """
+        args = peel_arg_shapes(n=n, nnz_pad=nnz_pad, slots=slots)
+        if self.backend == "fused":
+            p, _slot_ids, k0, single_level, alive0, frozen, frozen_truss = args
+            # Per-lane and per-slot fields reuse those arguments' shapes.
+            state = PeelState(
+                alive=alive0,
+                support=frozen_truss,
+                trussness=frozen_truss,
+                cur_k=k0,
+                kmax=k0,
+                levels=k0,
+                iters=k0,
+                done=single_level,
+                total_iters=jax.ShapeDtypeStruct((), jnp.int32),
+                edges_alive=k0,
+            )
+            self._level_step = self._level_step.lower(
+                p, state, frozen, frozen_truss, single_level
+            ).compile()
+            return
+        if self.mesh is not None:
+            from ..distributed.ktruss import peel_arg_shardings
+
+            args = jax.tree.map(
+                lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+                args,
+                peel_arg_shardings(self.mesh),
+            )
+        self._peel = self._peel.lower(*args).compile()
 
     def peel(
         self,

@@ -203,10 +203,19 @@ def candidate_configs(
     slot_nnz: int,
     *,
     blocks: Iterable[int] = DEFAULT_BLOCKS,
-    schedules: Iterable[str] = DEFAULT_SCHEDULES,
+    schedules: Iterable[str] | None = None,
     xla_flag_sets: Iterable[tuple[str, ...]] = DEFAULT_XLA_FLAG_SETS,
 ) -> tuple[FusedConfig, ...]:
-    """The default sweep grid, clamped to ``slot_nnz`` and deduplicated."""
+    """The default sweep grid, clamped to ``slot_nnz`` and deduplicated.
+
+    ``schedules=None`` sweeps every schedule off a TPU and only
+    ``compare`` on one: Mosaic has no lowering for the ``take_along_axis``
+    gathers of ``bsearch``."""
+    if schedules is None:
+        import jax
+
+        on_tpu = jax.default_backend() == "tpu"
+        schedules = ("compare",) if on_tpu else DEFAULT_SCHEDULES
     out: list[FusedConfig] = []
     seen: set[tuple] = set()
     for block in blocks:

@@ -51,7 +51,6 @@ def support_fine(
     if nnzp % chunk or chunk % tile:
         raise ValueError(f"need tile | chunk | nnz_pad, got {tile}/{chunk}/{nnzp}")
     w = _round_up(max(int(window), _LANES), _LANES)
-    interpret = (not on_tpu()) if interpret is None else interpret
 
     unnzp = int(p.ucolidx.shape[0])
     large = jnp.int32(p.n + 2)
@@ -124,7 +123,6 @@ def support_dense(
     u_sym: jax.Array, *, block: int = 128, interpret: bool | None = None
 ) -> jax.Array:
     """S = (U @ U) ∘ U with automatic padding to the block size."""
-    interpret = (not on_tpu()) if interpret is None else interpret
     v = u_sym.shape[0]
     vp = _round_up(v, block)
     u = jnp.zeros((vp, vp), jnp.float32).at[:v, :v].set(u_sym.astype(jnp.float32))

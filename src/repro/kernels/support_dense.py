@@ -43,13 +43,16 @@ def _kernel(u_ik_ref, u_kj_ref, u_ij_ref, out_ref, acc_ref, *, k_steps: int):
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def support_dense_pallas(
-    u_sym: jax.Array, *, block: int = 128, interpret: bool = True
+    u_sym: jax.Array, *, block: int = 128, interpret: bool | None = None
 ) -> jax.Array:
     """S = (U @ U) ∘ U for a dense 0/1 symmetric adjacency (f32).
 
     V must be a multiple of ``block`` (the ops.py wrapper pads; padded
-    rows/cols are all-zero so they contribute nothing).
+    rows/cols are all-zero so they contribute nothing).  ``interpret=None``
+    runs the Pallas interpreter off a TPU only.
     """
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     v = u_sym.shape[0]
     if u_sym.shape != (v, v):
         raise ValueError(f"expected square adjacency, got {u_sym.shape}")

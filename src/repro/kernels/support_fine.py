@@ -91,13 +91,14 @@ def support_fine_pallas(
     *,
     tile: int = 256,
     schedule: str = "compare",
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Intersection counts for E edges from pre-gathered (E, W) windows.
 
     Args / semantics match :func:`repro.kernels.ref.support_tiles_ref`.
     E must be a multiple of ``tile``; W a multiple of 128 (the wrapper in
-    ``ops.py`` pads both).
+    ``ops.py`` pads both).  ``interpret=None`` runs the Pallas interpreter
+    off a TPU only.
 
     Precondition (CSR rows satisfy it by construction): valid lanes of
     ``b_nav`` are **strictly** ascending — the ``bsearch`` schedule locates
@@ -110,6 +111,8 @@ def support_fine_pallas(
     if w % _LANES:
         raise ValueError(f"W={w} not a multiple of {_LANES}")
     kernel = _kernel_compare if schedule == "compare" else _kernel_bsearch
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
 
     in_spec = pl.BlockSpec((tile, w), lambda g: (g, 0))
     out = pl.pallas_call(

@@ -20,7 +20,11 @@ in front of them.  Its jobs:
   retried update exactly-once across the handoff;
 * **restart** — a killed/crashed replica is respawned (bounded by
   ``max_restarts``) and reinstated into routing; its persistent compile
-  cache (when configured) makes the comeback warm.
+  cache (when configured) makes the comeback warm;
+* **one chip per replica** — a TPU chip belongs to one process, so on a
+  host with TPU chips replica ``i`` is pinned to chip ``i`` and a fleet
+  larger than the host's chip count is refused with a typed
+  :class:`~repro.errors.DeviceError` (:func:`tpu_chips`).
 
 Everything is local-process by design (the wire protocol is the only
 coupling), so the integration tests exercise real process death, not a
@@ -30,6 +34,7 @@ simulation of it.
 from __future__ import annotations
 
 import contextlib
+import glob
 import os
 import subprocess
 import sys
@@ -42,15 +47,31 @@ from .replica import ReplicaConfig
 from .router import ReplicaHandle, Router
 from .wire import encode_graph
 
-__all__ = ["ManagedReplica", "Fleet"]
+__all__ = ["ManagedReplica", "Fleet", "tpu_chips"]
 
 _SRC_DIR = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+
+# libtpu's port for a process that owns one chip of the host; one per replica.
+_TPU_PROCESS_PORT_BASE = 8476
+
+
+def tpu_chips() -> int:
+    """TPU chips this process could hand to replica processes: the chip
+    device nodes it can see (``/dev/vfio/<n>`` on v5e, ``/dev/accel<n>``
+    on older generations), read without initializing a JAX backend so the
+    chips stay free; 0 when ``JAX_PLATFORMS`` leaves the TPU out."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return 0
+    return len(glob.glob("/dev/vfio/[0-9]*") + glob.glob("/dev/accel[0-9]*"))
 
 
 class ManagedReplica:
     """One replica process under fleet management."""
 
-    def __init__(self, config: ReplicaConfig, workdir: str):
+    def __init__(self, config: ReplicaConfig, workdir: str, index: int = 0):
+        self.index = index  # also the TPU chip the replica is pinned to
         self.config = config
         self.workdir = workdir
         self.process: subprocess.Popen | None = None
@@ -110,6 +131,13 @@ class Fleet:
     ):
         if size < 1:
             raise ValueError("a fleet needs at least one replica")
+        self.tpu_chips = tpu_chips()
+        if self.tpu_chips and size > self.tpu_chips:
+            raise DeviceError(
+                f"a fleet of {size} replicas needs {size} TPU chips (one "
+                f"process per chip); this host has {self.tpu_chips}",
+                site="fleet",
+            )
         self.workdir = os.path.abspath(workdir)
         self.checkpoint_root = os.path.join(self.workdir, "checkpoints")
         self.max_restarts = int(max_restarts)
@@ -141,7 +169,7 @@ class Fleet:
                 max_live=max_live,
                 warmup=per_warm,
             )
-            self._replicas[name] = ManagedReplica(cfg, rdir)
+            self._replicas[name] = ManagedReplica(cfg, rdir, index=i)
 
     # ------------------------------------------------------------------ #
     # Lifecycle
@@ -186,6 +214,7 @@ class Fleet:
         # A replica must never inherit the fleet's chaos plan — faults
         # against replicas are the *fleet's* to inject, not theirs.
         env.pop("REPRO_FAULTS", None)
+        env.update(self.replica_env(mr.index))
         log = open(os.path.join(mr.workdir, "log.txt"), "ab")
         try:
             mr.process = subprocess.Popen(
@@ -198,6 +227,18 @@ class Fleet:
         finally:
             log.close()
         mr.stopped = False
+
+    def replica_env(self, index: int) -> dict[str, str]:
+        """Environment that pins replica ``index`` to TPU chip ``index``
+        (empty off a TPU host)."""
+        if not self.tpu_chips:
+            return {}
+        return {
+            "TPU_VISIBLE_CHIPS": str(index),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_PORT": str(_TPU_PROCESS_PORT_BASE + index),
+        }
 
     def _await_port(self, mr: ManagedReplica, deadline: float) -> int:
         while time.monotonic() < deadline:

@@ -7,14 +7,21 @@ persistent-cache **hit on its first compile** (counted via JAX's own
 ``/jax/compilation_cache/cache_hits`` monitoring event — no timing
 heuristics).  Subprocesses are required because the persistent cache is
 keyed per process lifetime and must observe the config before first use.
+
+With no explicit directory, :func:`repro.api.cache.enable_persistent_cache`
+uses ``$JAX_COMPILATION_CACHE_DIR`` when it is set and configures no other
+directory; otherwise it uses the fixed ``.jax_cache`` at the checkout root.
 """
 
 import os
 import subprocess
 import sys
 
+from repro.api.cache import persistent_cache_dir
+
 _SCRIPT = """
 import sys
+import jax
 import jax.monitoring
 
 hits = []
@@ -24,20 +31,29 @@ jax.monitoring.register_event_listener(
     else None
 )
 
+from repro.api.cache import enable_persistent_cache
 from repro.graphs import erdos
 from repro.service import TrussService
 
-svc = TrussService(max_batch=1, chunk=64, cache_dir=sys.argv[1])
+# "-": no explicit directory; the helper picks the default.
+explicit = None if sys.argv[1] == "-" else sys.argv[1]
+if explicit is None:
+    print(f"PERSIST_DIR={enable_persistent_cache()}")
+svc = TrussService(max_batch=1, chunk=64, cache_dir=explicit)
 fut = svc.submit_decompose(erdos(40, 5.0, seed=0))
 svc.flush()
 assert fut.result().kmax >= 2
+print(f"PERSIST_CONFIGURED={jax.config.jax_compilation_cache_dir}")
 print(f"PERSIST_HITS={len(hits)}")
 print(f"PERSIST_COMPILES={svc.stats()['cache_compiles']}")
 """
 
 
-def _run(cache_dir: str) -> dict:
+def _run(cache_dir: str, env_cache_dir: str | None = None) -> dict:
     env = dict(os.environ)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if env_cache_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = env_cache_dir
     src = os.path.join(os.path.dirname(__file__), os.pardir, "src")
     env["PYTHONPATH"] = os.path.abspath(src) + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.run(
@@ -69,3 +85,23 @@ def test_fresh_process_reports_warm_first_compile(tmp_path):
     # XLA compile underneath was served from the persistent cache.
     assert warm["PERSIST_COMPILES"] == "1"
     assert int(warm["PERSIST_HITS"]) >= 1, warm
+
+
+def test_env_cache_dir_is_the_only_one_configured(tmp_path):
+    env_dir = str(tmp_path / "env-cache")
+    cold = _run("-", env_cache_dir=env_dir)
+    assert cold["PERSIST_DIR"] == env_dir
+    assert cold["PERSIST_CONFIGURED"] == env_dir
+    assert os.listdir(env_dir), "no entries under JAX_COMPILATION_CACHE_DIR"
+    warm = _run("-", env_cache_dir=env_dir)
+    assert int(warm["PERSIST_HITS"]) >= 1, warm
+
+
+def test_default_cache_dir_is_fixed_inside_the_checkout(monkeypatch):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    first, second = persistent_cache_dir(), persistent_cache_dir()
+    assert first == second
+    root = os.path.abspath(os.path.join(os.path.dirname(__file__), os.pardir))
+    assert first == os.path.join(root, ".jax_cache")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    assert persistent_cache_dir() == "/elsewhere/cache"

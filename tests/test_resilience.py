@@ -235,6 +235,32 @@ def test_compile_cache_wraps_builder_failures():
     assert cache.stats.compiles == 0  # failed builds are not compiles
 
 
+def test_refused_compile_is_a_compile_error_at_executor_build(monkeypatch):
+    """Executors compile ahead of time in the cache's builder, so a
+    program the device's compiler refuses fails as a typed CompileError
+    before any dispatch — not as a DeviceError at the first dispatch."""
+    from repro.exec import PeelExecutor
+
+    def refuse(self, **shapes):
+        raise NotImplementedError("Only 2D gather is supported")
+
+    monkeypatch.setattr(PeelExecutor, "compile", refuse)
+    s = Session(
+        backend="fine/xla/aligned",
+        max_batch=2,
+        chunk=64,
+        retry=RetryPolicy(max_attempts=1, fallback=False, bisect=False),
+    )
+    fut = s.submit(TrussQuery.decompose(tiny()))
+    s.flush()
+    with pytest.raises(QueryFailedError) as ei:
+        fut.result()
+    assert isinstance(ei.value.cause, CompileError)
+    assert "2D gather" in str(ei.value.cause)
+    assert s.device_dispatches == 0 and s.retries == 0
+    assert s.backend_fallbacks == 0 and s.queries_failed == 1
+
+
 # --------------------------------------------------------------------- #
 # (e) Batch fault isolation end to end
 # --------------------------------------------------------------------- #

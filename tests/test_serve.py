@@ -20,6 +20,7 @@ import pytest
 from repro.api import Session, TrussQuery, solve
 from repro.api.cache import bucket_for, bucket_str
 from repro.errors import (
+    DeviceError,
     InvalidGraphError,
     QueryFailedError,
     TrussTimeoutError,
@@ -568,6 +569,37 @@ def test_replica_restore_stream_resumes_from_checkpoint(replica):
     # And the retried update is recognized as already applied.
     replay = twin._handle(msg)
     assert replay["replayed"] is True
+
+
+# ------------------------------------------------------------------ #
+# One TPU chip per replica process
+# ------------------------------------------------------------------ #
+def test_fleet_refuses_more_tpu_replicas_than_chips(monkeypatch, tmp_path):
+    import repro.serve.fleet as fleet_mod
+
+    monkeypatch.setattr(fleet_mod, "tpu_chips", lambda: 1)
+    with pytest.raises(DeviceError, match="3 TPU chips.*has 1"):
+        Fleet(3, workdir=str(tmp_path / "fleet"))
+
+
+def test_fleet_pins_each_tpu_replica_to_its_own_chip(monkeypatch, tmp_path):
+    import repro.serve.fleet as fleet_mod
+
+    monkeypatch.setattr(fleet_mod, "tpu_chips", lambda: 4)
+    fleet = Fleet(3, workdir=str(tmp_path / "fleet"))
+    envs = [fleet.replica_env(i) for i in range(3)]
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2"]
+    assert len({e["TPU_PROCESS_PORT"] for e in envs}) == 3
+    assert all(e["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1" for e in envs)
+    monkeypatch.setattr(fleet_mod, "tpu_chips", lambda: 0)
+    assert Fleet(3, workdir=str(tmp_path / "cpu")).replica_env(1) == {}
+
+
+def test_tpu_chips_is_zero_when_jax_platforms_excludes_tpu(monkeypatch):
+    from repro.serve.fleet import tpu_chips
+
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert tpu_chips() == 0
 
 
 # ------------------------------------------------------------------ #

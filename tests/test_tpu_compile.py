@@ -1,0 +1,115 @@
+"""Ahead-of-time compiles for a described TPU v5e, with no chip attached.
+
+The TPU compiler is installed wherever ``libtpu`` is, and compiles for a
+topology that is described rather than attached.  These tests compile the
+peel programs the auto rule sends to the chip — the XLA peel in each
+formulation and dataflow, one chip at a bucket of ``chip_smoke.py``, and
+the slot-sharded peel over the four chips of a ``v5e:2x2`` — so a program
+the chip's compiler refuses fails here, at no chip time.  Nothing runs:
+results and times need the chip.
+
+The topology is described inside a fixture, never at import: only one
+process may load the TPU library at a time, and every test worker imports
+this file.  The persistent compile cache is off around these compiles — an
+entry compiled for a described chip cannot be read back without one.
+"""
+
+import os
+
+import numpy as np
+import pytest
+
+import jax
+from jax.sharding import Mesh, SingleDeviceSharding
+
+from repro.distributed.ktruss import SLOT_AXIS, peel_arg_shardings
+from repro.exec.peel import build_peel, make_problem_support, peel_arg_shapes
+
+# Buckets (n_pad, nnz_pad, window) and slot counts of chip_smoke.py's
+# phases: large-static rmat-9 (the auto rule picks fine/xla) and road-512
+# (coarse/xla) on 2 slots; many-small on 8.
+RMAT_BUCKET, ROAD_BUCKET, STATIC_SLOTS = (512, 4096, 256), (262144, 1048576, 8), 2
+SMALL_BUCKET, SMALL_SLOTS = (512, 4096, 32), 8
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    was_enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure to describe is a skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was_enabled)
+        compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _placed(args, shardings):
+    return jax.tree.map(
+        lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+        args,
+        shardings,
+    )
+
+
+def _peel_program(granularity, mode, window):
+    support = make_problem_support(
+        granularity=granularity, mode=mode, backend="xla", window=window, chunk=256
+    )
+    return build_peel(support)
+
+
+@pytest.mark.parametrize(
+    "granularity,mode,bucket",
+    [
+        ("fine", "eager", RMAT_BUCKET),
+        ("fine", "owner", RMAT_BUCKET),
+        ("coarse", "eager", ROAD_BUCKET),
+    ],
+    ids=["fine-eager", "fine-owner", "coarse"],
+)
+def test_xla_peel_compiles_for_one_v5e_chip(one_chip, granularity, mode, bucket):
+    n_pad, nnz_pad, window = bucket
+    args = peel_arg_shapes(
+        n=STATIC_SLOTS * n_pad, nnz_pad=STATIC_SLOTS * nnz_pad, slots=STATIC_SLOTS
+    )
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), args
+    )
+    compiled = _peel_program(granularity, mode, window).lower(*args).compile()
+    assert "while" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**30
+
+
+def test_sharded_peel_compiles_for_v5e_2x2(topo):
+    mesh = Mesh(
+        np.array(topo.devices),
+        (SLOT_AXIS,),
+        axis_types=(jax.sharding.AxisType.Auto,),
+    )
+    n_pad, nnz_pad, window = SMALL_BUCKET
+    args = peel_arg_shapes(
+        n=SMALL_SLOTS * n_pad, nnz_pad=SMALL_SLOTS * nnz_pad, slots=SMALL_SLOTS
+    )
+    compiled = (
+        _peel_program("fine", "eager", window)
+        .lower(*_placed(args, peel_arg_shardings(mesh)))
+        .compile()
+    )
+    # Slot blocks stay sharded: every edge-lane input keeps its "slots"
+    # partitioning, and the devices meet only in reductions.
+    (p, slot_ids, *_rest), _kwargs = compiled.input_shardings
+    assert p.colidx.spec == jax.sharding.PartitionSpec(SLOT_AXIS)
+    assert slot_ids.spec == jax.sharding.PartitionSpec(SLOT_AXIS)
+    assert "all-gather" not in compiled.as_text()
