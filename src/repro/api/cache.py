@@ -279,5 +279,10 @@ class CompileCache:
             seen = {bucket_str(b) for (b, _slots, _variant) in self._exes}
         return tuple(sorted(seen))
 
+    def executors(self) -> list[Callable]:
+        """The cached executors, in the order they were built."""
+        with self._lock:
+            return list(self._exes.values())
+
     def __len__(self) -> int:
         return len(self._exes)

@@ -54,7 +54,9 @@ class RequestStats:
 
     queue_time_s: float = 0.0  # submit -> batch formation
     pack_time_s: float = 0.0  # host-side block-diagonal packing (shared)
-    device_time_s: float = 0.0  # the batch's single peel dispatch (shared)
+    # Host wall time from the peel's launch to its readback (shared): the
+    # device's run plus whatever the host did meanwhile; not device time.
+    device_time_s: float = 0.0
     plan_time_s: float = 0.0  # bucket + backend assignment for THIS query
     compile_hit: bool = False  # did the batch reuse a cached executable
     bucket: Optional[Bucket] = None
@@ -102,12 +104,17 @@ class QueryState:
 
 @dataclasses.dataclass
 class PlannedBatch:
-    """One packed dispatch: same-(bucket, backend) queries on ``slots`` slots."""
+    """One packed dispatch: same-(bucket, backend) queries on ``slots`` slots.
+
+    ``id`` is the owning session's batch number (``None`` outside a
+    session); every span of the batch's lowering carries it as ``batch``.
+    """
 
     bucket: Bucket
     backend: BackendKey
     queries: list[QueryState]
     slots: int
+    id: int | None = None
 
 
 @dataclasses.dataclass
@@ -389,8 +396,13 @@ class Planner:
 
         Returns one result per query, in batch order: ``KTrussResult``
         (ktruss), ``int`` (kmax), ``TrussDecomposition`` (decompose), or
-        the member's full ``(nnz,)`` trussness (stream_update).
+        the member's full ``(nnz,)`` trussness (stream_update).  Every
+        span of the lowering carries the batch's id as ``batch``.
         """
+        with current_tracer().tagged(batch=batch.id):
+            return self._execute(batch, cache)
+
+    def _execute(self, batch: PlannedBatch, cache: CompileCache) -> list[Any]:
         bucket, backend, queries = batch.bucket, batch.backend, batch.queries
         tracer = current_tracer()
         qids = tuple(st.id for st in queries)
@@ -495,7 +507,7 @@ class Planner:
             ) from e
         dt = obs_clock.now() - t0
 
-        with tracer.span("unpack", members=len(queries)):
+        with tracer.span("unpack", members=len(queries)) as span:
             alive = np.asarray(st_dev.alive)
             support = np.asarray(st_dev.support)
             trussness = np.asarray(st_dev.trussness)
@@ -503,6 +515,9 @@ class Planner:
             levels = np.asarray(st_dev.levels)
             iters = np.asarray(st_dev.iters)
             edges_alive = np.asarray(st_dev.edges_alive)
+            # The slot that retired last was live on every trip, so the
+            # largest per-slot count is the loop's trip count.
+            span.attrs["trips"] = int(iters.max(initial=0))
 
             results: list[Any] = []
             for i, (st, (a, b)) in enumerate(zip(queries, packed.edge_ranges)):

@@ -15,6 +15,7 @@ module now; the lowering itself lives in :class:`repro.api.Planner`.
 from __future__ import annotations
 
 import contextlib
+import itertools
 import threading
 from collections import deque
 from typing import Any
@@ -24,6 +25,7 @@ import numpy as np
 from ..graphs.csr import CSRGraph
 from ..obs import MetricsRegistry, Observability
 from ..obs import clock as obs_clock
+from ..obs.trace import QUEUE_TRACK
 from ..resilience.faults import FaultPlan, use_plan
 from ..resilience.retry import RetryPolicy
 from ..resilience.runner import ResilientRunner
@@ -292,6 +294,7 @@ class Session:
         self.queue = QueryQueue(max_batch=max_batch)  # guarded-by: _cv
         self._futures: dict[int, TrussFuture] = {}  # guarded-by: _cv
         self._inflight: set[int] = set()  # guarded-by: _cv
+        self._batch_ids = itertools.count()  # PlannedBatch.id, in formation order
         self.faults = faults if faults is not None else FaultPlan.from_env()
         self.retry = retry or RetryPolicy()
         self.shed_on_timeout = bool(shed_on_timeout)
@@ -327,6 +330,8 @@ class Session:
 
     @property
     def device_time_s(self) -> float:
+        """Host wall time from each peel's launch to its readback, summed
+        over the dispatches (not device time: a profiler trace has that)."""
         return self.obs.metrics.value("device_seconds_total")
 
     @property
@@ -409,7 +414,7 @@ class Session:
                 for st in batch.queries:
                     st.stats.queue_time_s = now - st.submitted_at
                     st.stats.batch_size = len(batch.queries)
-                self._run_batch(batch)
+                self._run_batch(self._numbered(batch))
             results = [f.result() for f in futs]
         self.obs.export_trace()  # no-op unless a trace path is configured
         return results
@@ -501,12 +506,30 @@ class Session:
 
     def _planned(self, batch: list[QueryState]) -> PlannedBatch:
         """Wrap a queue-formed (single-group) batch for the planner."""
-        return PlannedBatch(
-            bucket=batch[0].bucket,
-            backend=batch[0].backend,
-            queries=batch,
-            slots=self.planner.max_batch,
+        return self._numbered(
+            PlannedBatch(
+                bucket=batch[0].bucket,
+                backend=batch[0].backend,
+                queries=batch,
+                slots=self.planner.max_batch,
+            )
         )
+
+    def _numbered(self, planned: PlannedBatch) -> PlannedBatch:
+        """Give a newly formed batch the session's next batch id, and trace
+        each member's wait from submission to formation as a ``queue``
+        span (on the queue's own track)."""
+        planned.id = next(self._batch_ids)
+        tracer = self.obs.tracer
+        for st in planned.queries:
+            tracer.complete(
+                "queue",
+                st.submitted_at,
+                st.submitted_at + st.stats.queue_time_s,
+                tid=QUEUE_TRACK,
+                batch=planned.id,
+            )
+        return planned
 
     def _dispatch_once(self, planned: PlannedBatch) -> list[Any]:
         """One attempt at one packed dispatch (the runner's retry unit).

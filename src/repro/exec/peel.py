@@ -239,49 +239,54 @@ def build_peel(
             return jnp.any(~st.done) & (st.total_iters < limit)
 
         def body(st: PeelState) -> PeelState:
-            # Frozen lanes participate in supports exactly while the slot's
-            # threshold is inside their truss: at level k the from-scratch
-            # k-truss contains a frozen edge iff its trussness >= k, so the
-            # restricted peel over the free lanes sees the same subgraph.
-            eff_alive = st.alive | (frozen & (frozen_truss >= st.cur_k[slot_ids]))
-            s = support(p, eff_alive)
-            thresh = (st.cur_k - 2)[slot_ids]
-            new_alive = st.alive & (s >= thresh)
-            changed = seg((new_alive ^ st.alive).astype(jnp.int32), slot_ids)
-            converged = (changed == 0) & ~st.done
-            conv_lane = converged[slot_ids]
-            trussness = jnp.where(
-                conv_lane & new_alive, st.cur_k[slot_ids], st.trussness
-            )
-            left = seg(new_alive.astype(jnp.int32), slot_ids)
-            nonempty = left > 0
-            retired = converged & (~nonempty | single_level)
-            cur_k = jnp.where(converged & ~retired, st.cur_k + 1, st.cur_k)
-            # Prune-ahead: slots that just advanced re-prune against their
-            # new threshold using the support already in hand (the free mask
-            # is unchanged, so s IS the next level's first support; with
-            # frozen lanes s only over-counts — support is monotone in the
-            # alive set — so every ahead-pruned edge would be pruned by the
-            # next level's first true support anyway) — saving one full
-            # support evaluation per level, the peel's dominant cost.
-            # Retired/done slots see their old threshold: idempotent.
-            new_alive = new_alive & (s >= (cur_k - 2)[slot_ids])
-            return PeelState(
-                alive=new_alive,
-                support=s * new_alive.astype(s.dtype),
-                trussness=trussness,
-                cur_k=cur_k,
-                kmax=jnp.where(converged & nonempty, st.cur_k, st.kmax),
-                levels=st.levels + converged.astype(jnp.int32),
-                iters=st.iters + (~st.done).astype(jnp.int32),
-                done=st.done | retired,
-                total_iters=st.total_iters + 1,
-                # Live slots track their current level's alive-edge count;
-                # a retired slot freezes at its final level — free per-slot
-                # telemetry for the runtime imbalance histograms
-                # (repro.obs.peel_stats).
-                edges_alive=jnp.where(st.done, st.edges_alive, left),
-            )
+            # The two named scopes split a trip's device time in a profiler
+            # trace: the support pass, and the prune with its bookkeeping.
+            with jax.named_scope("support"):
+                # Frozen lanes participate in supports exactly while the
+                # slot's threshold is inside their truss: at level k the
+                # from-scratch k-truss contains a frozen edge iff its
+                # trussness >= k, so the restricted peel over the free
+                # lanes sees the same subgraph.
+                eff_alive = st.alive | (frozen & (frozen_truss >= st.cur_k[slot_ids]))
+                s = support(p, eff_alive)
+            with jax.named_scope("prune"):
+                thresh = (st.cur_k - 2)[slot_ids]
+                new_alive = st.alive & (s >= thresh)
+                changed = seg((new_alive ^ st.alive).astype(jnp.int32), slot_ids)
+                converged = (changed == 0) & ~st.done
+                conv_lane = converged[slot_ids]
+                trussness = jnp.where(
+                    conv_lane & new_alive, st.cur_k[slot_ids], st.trussness
+                )
+                left = seg(new_alive.astype(jnp.int32), slot_ids)
+                nonempty = left > 0
+                retired = converged & (~nonempty | single_level)
+                cur_k = jnp.where(converged & ~retired, st.cur_k + 1, st.cur_k)
+                # Prune-ahead: slots that just advanced re-prune against their
+                # new threshold using the support already in hand (the free mask
+                # is unchanged, so s IS the next level's first support; with
+                # frozen lanes s only over-counts — support is monotone in the
+                # alive set — so every ahead-pruned edge would be pruned by the
+                # next level's first true support anyway) — saving one full
+                # support evaluation per level, the peel's dominant cost.
+                # Retired/done slots see their old threshold: idempotent.
+                new_alive = new_alive & (s >= (cur_k - 2)[slot_ids])
+                return PeelState(
+                    alive=new_alive,
+                    support=s * new_alive.astype(s.dtype),
+                    trussness=trussness,
+                    cur_k=cur_k,
+                    kmax=jnp.where(converged & nonempty, st.cur_k, st.kmax),
+                    levels=st.levels + converged.astype(jnp.int32),
+                    iters=st.iters + (~st.done).astype(jnp.int32),
+                    done=st.done | retired,
+                    total_iters=st.total_iters + 1,
+                    # Live slots track their current level's alive-edge count;
+                    # a retired slot freezes at its final level — free per-slot
+                    # telemetry for the runtime imbalance histograms
+                    # (repro.obs.peel_stats).
+                    edges_alive=jnp.where(st.done, st.edges_alive, left),
+                )
 
         return jax.lax.while_loop(cond, body, state)
 
@@ -445,6 +450,14 @@ class PeelExecutor:
             )
         self._peel = self._peel.lower(*args).compile()
 
+    def compiled_text(self) -> str | None:
+        """The compiled peel's HLO text, after XLA's passes: the
+        instruction names a profiler trace shows, each with its scope in
+        ``op_name`` (``.../while/body/support/...``).  ``None`` until
+        :meth:`compile` has run."""
+        as_text = getattr(self._peel, "as_text", None)
+        return as_text() if as_text is not None else None
+
     def peel(
         self,
         p: FineProblem,
@@ -463,43 +476,44 @@ class PeelExecutor:
         ``frozen`` disjoint.  Defaults (all-free) reproduce the plain
         from-scratch peel bit-for-bit.
         """
-        k0 = jnp.asarray(np.asarray(k0, dtype=np.int32))
-        num_slots = int(k0.shape[0])
-        if single_level is None:
-            single_level = np.zeros(num_slots, dtype=bool)
-        single_level = jnp.asarray(np.asarray(single_level, dtype=bool))
-        slot_ids = jnp.asarray(np.asarray(slot_ids, dtype=np.int32))
-        if alive0 is None:
-            alive0 = p.colidx != 0
-        if frozen is None:
-            frozen = jnp.zeros(alive0.shape, bool)
-        if frozen_truss is None:
-            frozen_truss = jnp.zeros(alive0.shape, jnp.int32)
-        if self.backend == "fused":
-            # The megakernel tiles lanes by `block` and reduces per-slot
-            # by reshaping to (slots, slot_nnz): refuse mis-tiled packs
-            # loudly (typed, slot-attributed) instead of mixing members.
-            from ..graphs.pack import validate_fused_tiling
-
-            validate_fused_tiling(
-                p, slots=num_slots, block=self.fused_config.block
-            )
-        if self.mesh is not None:
-            from ..distributed.ktruss import shard_peel_args
-
-            (p, slot_ids, k0, single_level, alive0, frozen, frozen_truss) = (
-                shard_peel_args(
-                    self.mesh, p, slot_ids, k0, single_level, alive0,
-                    frozen, frozen_truss,
-                )
-            )
-        self.dispatches += 1
-        current_registry().inc("peel_dispatches")
         tracer = current_tracer()
-        # "dispatch" is the (async) launch of the compiled peel — on a
+        num_slots = len(k0)
+        # "dispatch" is the host's side of the launch: the arguments built
+        # and placed, then the (async) launch of the compiled peel — on a
         # first call per executor it includes the XLA compile; the
         # blocking readback below is the true device wait.
         with tracer.span("dispatch", slots=num_slots):
+            k0 = jnp.asarray(np.asarray(k0, dtype=np.int32))
+            if single_level is None:
+                single_level = np.zeros(num_slots, dtype=bool)
+            single_level = jnp.asarray(np.asarray(single_level, dtype=bool))
+            slot_ids = jnp.asarray(np.asarray(slot_ids, dtype=np.int32))
+            if alive0 is None:
+                alive0 = p.colidx != 0
+            if frozen is None:
+                frozen = jnp.zeros(alive0.shape, bool)
+            if frozen_truss is None:
+                frozen_truss = jnp.zeros(alive0.shape, jnp.int32)
+            if self.backend == "fused":
+                # The megakernel tiles lanes by `block` and reduces per-slot
+                # by reshaping to (slots, slot_nnz): refuse mis-tiled packs
+                # loudly (typed, slot-attributed) instead of mixing members.
+                from ..graphs.pack import validate_fused_tiling
+
+                validate_fused_tiling(
+                    p, slots=num_slots, block=self.fused_config.block
+                )
+            if self.mesh is not None:
+                from ..distributed.ktruss import shard_peel_args
+
+                (p, slot_ids, k0, single_level, alive0, frozen, frozen_truss) = (
+                    shard_peel_args(
+                        self.mesh, p, slot_ids, k0, single_level, alive0,
+                        frozen, frozen_truss,
+                    )
+                )
+            self.dispatches += 1
+            current_registry().inc("peel_dispatches")
             st = self._peel(
                 p, slot_ids, k0, single_level, alive0, frozen, frozen_truss
             )

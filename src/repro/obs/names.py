@@ -22,11 +22,10 @@ COUNTERS: frozenset[str] = frozenset(
         "batch_bisects",
         "batches_run",
         "deadline_misses",
-        "device_seconds_total",
+        "device_seconds_total",  # host wall time, peel launch to readback
         "dispatch_failures",
         "dispatches",
         "peel_batches",
-        "peel_device_seconds_total",
         "peel_dispatches",
         "peel_fused_levels",
         "peel_slots",

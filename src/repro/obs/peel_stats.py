@@ -16,7 +16,8 @@ planner's auto rule can later be calibrated from observed device time
 instead of the static two-threshold heuristic (see ROADMAP's cost-model
 item): the registry accumulates, per backend per shape class,
 
-* ``peel_device_time_s``   — dispatch wall time histogram,
+* ``peel_device_time_s``   — host wall time from each dispatch's launch to
+  its readback (not device time: a profiler trace has that),
 * ``peel_slot_iters``      — per-slot iteration histogram (the
   imbalance's raw material),
 * ``peel_batch_imbalance`` — per-batch max/mean slot-iteration ratio
@@ -59,7 +60,7 @@ class PeelBatchTelemetry:
     mean_iters: float
     imbalance: float  # max/mean slot iterations; 1.0 == balanced
     max_levels: int
-    device_time_s: float
+    device_time_s: float  # host wall time, launch to readback
 
 
 def record_peel_batch(
@@ -93,7 +94,6 @@ def record_peel_batch(
 
     m.inc("peel_batches", **labels)
     m.inc("peel_slots", b, **labels)
-    m.inc("peel_device_seconds_total", device_time_s, **labels)
     m.observe("peel_device_time_s", device_time_s, **labels)
     m.observe("peel_batch_imbalance", imb, buckets=IMBALANCE_BUCKETS, **labels)
     for it in live_iters.tolist():
