@@ -21,6 +21,12 @@ Two execution modes (DESIGN.md §4):
 
 All shapes are static: windows of width ``window`` (≥ max degree), tasks
 processed in chunks of ``chunk`` via ``lax.scan`` to bound memory.
+
+The eager mode intersects a task's two windows by one broadcast compare,
+O(W²) per task with no gather.  On a TPU v5e one gathered element costs
+as much as thousands of compares, so this beats a binary search of
+element gathers, O(W log W), at every window from 128 to 4,096 (PERF.md,
+section 6).  The owner mode still searches.
 """
 
 from __future__ import annotations
@@ -42,6 +48,10 @@ __all__ = [
     "support_fine_owner",
     "support_fine_stacked",
 ]
+
+# Row-κ entries compared per step of the eager intersection: one step's
+# compare is at most (chunk, window, _B_BLOCK).
+_B_BLOCK = 256
 
 
 class FineProblem(NamedTuple):
@@ -165,7 +175,6 @@ def support_fine_eager(
     if n_tasks % chunk:
         raise ValueError(f"tasks={n_tasks} not a multiple of chunk={chunk}")
     w = int(window)
-    large = jnp.int32(p.n + 2)
     offs = jnp.arange(w, dtype=jnp.int32)[None, :]
 
     def body(s_acc: jax.Array, chunk_start: jax.Array):
@@ -194,35 +203,35 @@ def support_fine_eager(
         a_alive = a_in & alive[a_idx_c]
         q = jnp.where(a_alive & valid_t[:, None], a_vals, 0)
 
-        # --- row-κ window (sorted navigation values) ---------------------
+        # --- row-κ window, keyed for the compare -------------------------
+        # Dead and out-of-row lanes read -1, which no query (0 or a 1-based
+        # vertex id) equals, so the masks fold into the key.
         b_start = p.rowptr[jnp.maximum(kappa, 1) - 1] * (kappa > 0)
-        b_idx = b_start[:, None] + offs
+        b_idx_c = jnp.clip(b_start[:, None] + offs, 0, nnzp - 1)
         b_in = offs < p.deg[kappa][:, None]
-        b_idx_c = jnp.clip(b_idx, 0, nnzp - 1)
-        b_nav = jnp.where(b_in, p.colidx[b_idx_c], large)
-        b_alive = b_in & alive[b_idx_c]
+        b_key = jnp.where(b_in & alive[b_idx_c], p.colidx[b_idx_c], -1)
 
-        if w <= 32:
-            # Small windows: O(W²) broadcast equality beats the binary
-            # search — no gathers at all (§Perf-ktruss iteration K2; also
-            # the schedule the Pallas kernel's "compare" path uses).
-            eq = (q[:, :, None] == b_nav[:, None, :]) & b_alive[:, None, :]
-            member = jnp.any(eq, axis=2)
-            pos_c = jnp.argmax(eq, axis=2).astype(jnp.int32)
-        else:
-            member, pos = sorted_window_member(q, b_nav)
-            pos_c = jnp.minimum(pos, w - 1)
-            member &= jnp.take_along_axis(b_alive, pos_c, axis=1, mode="clip")
-        ones = member.astype(jnp.int32)
+        # --- intersection: broadcast compare, no gathers -----------------
+        # Each window holds distinct ids, so a suffix entry matches at most
+        # one row-κ entry and the reverse.  Row κ is compared in blocks of
+        # at most _B_BLOCK entries to bound one step's (chunk, W, block)
+        # compare; XLA fuses each into its two reductions.
+        a_hit = jnp.zeros((chunk, w), bool)
+        b_hits = []
+        for k0 in range(0, w, _B_BLOCK):
+            eq = q[:, :, None] == b_key[:, None, k0 : k0 + _B_BLOCK]
+            a_hit |= jnp.any(eq, axis=2)
+            b_hits.append(jnp.any(eq, axis=1))
+        b_hit = jnp.concatenate(b_hits, axis=1)
 
         # u1: the task's own edge accumulates the intersection size.
-        s_acc = s_acc.at[t].add(jnp.sum(ones, axis=1) * valid_t.astype(jnp.int32))
+        s_acc = s_acc.at[t].add(jnp.sum(a_hit, axis=1, dtype=jnp.int32))
         # u2: matched suffix entries (edges (i, m)) — scatter to row i slots.
-        u2_tgt = jnp.where(member, a_idx_c, nnzp)
-        s_acc = s_acc.at[u2_tgt.reshape(-1)].add(ones.reshape(-1), mode="drop")
+        u2_tgt = jnp.where(a_hit, a_idx_c, nnzp)
+        s_acc = s_acc.at[u2_tgt.reshape(-1)].add(1, mode="drop")
         # u3: matched row-κ entries (edges (κ, m)) — scatter to row κ slots.
-        u3_tgt = jnp.where(member, b_start[:, None] + pos_c, nnzp)
-        s_acc = s_acc.at[u3_tgt.reshape(-1)].add(ones.reshape(-1), mode="drop")
+        u3_tgt = jnp.where(b_hit, b_idx_c, nnzp)
+        s_acc = s_acc.at[u3_tgt.reshape(-1)].add(1, mode="drop")
         return s_acc, None
 
     starts = jnp.arange(0, n_tasks, chunk, dtype=jnp.int32)

@@ -15,8 +15,10 @@ from repro.core import (
     support_fine_eager,
     support_fine_owner,
     support_numpy,
+    trussness_numpy,
 )
-from repro.graphs import CSRGraph, from_edges
+from repro.exec import PeelExecutor
+from repro.graphs import CSRGraph, from_edges, pack_problems
 
 import jax.numpy as jnp
 
@@ -161,3 +163,58 @@ def test_bucketed_fine_matches_oracle(small_graphs):
             alive_ref, s_ref = ktruss_numpy(g, k)
             assert np.array_equal(res.alive, alive_ref), (g.name, k)
             assert np.array_equal(res.support, s_ref), (g.name, k)
+
+
+# ------------------------------------------------------------------ #
+# Eager window intersection at windows of one and of several blocks
+# ------------------------------------------------------------------ #
+def _hub_graph(w, seed):
+    """A hub whose directed row is exactly ``w`` wide, below a vertex
+    linked to it, so the task (that vertex, hub) compares a suffix with a
+    full ``w``-wide row-κ window; chords among the hub's neighbours close
+    triangles at several truss levels."""
+    rng = np.random.default_rng(seed)
+    nbrs = np.arange(2, w + 2)  # 0-based: vertex 1 is the hub
+    hub = np.stack([np.ones(w, np.int64), nbrs], axis=1)
+    low = np.stack(
+        [np.zeros(w // 2 + 1, np.int64), np.r_[1, rng.choice(nbrs, w // 2, replace=False)]],
+        axis=1,
+    )
+    chords = rng.choice(nbrs, size=(w, 2))
+    return from_edges(w + 2, np.concatenate([hub, low, chords]), name=f"hub{w}-{seed}")
+
+
+@pytest.mark.parametrize("w", [8, 32, 64, 256, 512])
+def test_eager_intersection_matches_oracle_at_window(w):
+    graphs = [_hub_graph(w, seed) for seed in range(3)]
+    assert all(g.max_degree() == w for g in graphs)  # the hub sets W
+
+    # Dead lanes: a partly peeled alive mask.
+    g = graphs[0]
+    p = prepare_fine(g, chunk=64)
+    rng = np.random.default_rng(w)
+    alive_np = (rng.random(p.nnz_pad) < 0.7) & (np.asarray(p.colidx) != 0)
+    s = np.asarray(support_fine_eager(p, jnp.asarray(alive_np), window=w, chunk=64))
+    live = alive_np[: g.nnz]
+    assert np.array_equal(s[: g.nnz], support_numpy(g, live) * live)
+    assert not s[g.nnz :].any()
+
+    # A slot-aligned pack: windows of each slot's last rows run into the
+    # pad lanes before the next slot; the fourth slot is empty.
+    slot_nnz = max(64, 1 << max(g.nnz for g in graphs).bit_length())
+    pp = pack_problems(
+        graphs, slot_n=w + 2, slot_nnz=slot_nnz, slots=4, chunk=64, layout="aligned"
+    )
+    alive = jnp.asarray(pp.problem.colidx != 0)
+    s = np.asarray(support_fine_eager(pp.problem, alive, window=w, chunk=64))
+    for g, (a, b) in zip(graphs, pp.edge_ranges):
+        assert np.array_equal(s[a:b], support_numpy(g)), g.name
+    exe = PeelExecutor(mode="eager", backend="xla", window=w, chunk=64)
+    st = exe.peel(
+        pp.problem,
+        slot_ids=np.repeat(np.arange(4, dtype=np.int32), slot_nnz),
+        k0=[3] * 4,
+    )
+    truss = np.asarray(st.trussness)
+    for g, (a, b) in zip(graphs, pp.edge_ranges):
+        assert np.array_equal(truss[a:b], trussness_numpy(g)), g.name

@@ -30,6 +30,8 @@ from repro.exec.peel import build_peel, make_problem_support, peel_arg_shapes
 # (coarse/xla) on 2 slots; many-small on 8.
 RMAT_BUCKET, ROAD_BUCKET, STATIC_SLOTS = (512, 4096, 256), (262144, 1048576, 8), 2
 SMALL_BUCKET, SMALL_SLOTS = (512, 4096, 32), 8
+# The benchmark's buckets: Kronecker scale 8 on 1 slot, scale 7 on 8.
+KRON8_BUCKET, KRON7_BUCKET = (256, 4096, 256), (128, 1024, 128)
 
 
 @pytest.fixture(scope="module")
@@ -90,6 +92,26 @@ def test_xla_peel_compiles_for_one_v5e_chip(one_chip, granularity, mode, bucket)
     compiled = _peel_program(granularity, mode, window).lower(*args).compile()
     assert "while" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**30
+
+
+@pytest.mark.parametrize(
+    "bucket,slots", [(KRON8_BUCKET, 1), (KRON7_BUCKET, 8)], ids=["kron8", "kron7-b8"]
+)
+def test_fine_eager_support_intersects_without_search_gathers(one_chip, bucket, slots):
+    # The window intersection is a broadcast compare: the only gathers left
+    # in the support pass read the windows and the chunk's lanes, none is
+    # the binary search's take_along_axis.
+    n_pad, nnz_pad, window = bucket
+    args = peel_arg_shapes(n=slots * n_pad, nnz_pad=slots * nnz_pad, slots=slots)
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), args
+    )
+    text = _peel_program("fine", "eager", window).lower(*args).compile().as_text()
+    gathers = [
+        line for line in text.splitlines() if " gather(" in line and "/support/" in line
+    ]
+    assert gathers  # scope names reach the compiled program
+    assert not [line for line in gathers if "take_along_axis" in line]
 
 
 def test_sharded_peel_compiles_for_v5e_2x2(topo):
