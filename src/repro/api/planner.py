@@ -361,6 +361,15 @@ class Planner:
         )
         return exe
 
+    def chips_used(self, batch: PlannedBatch) -> int:
+        """Devices of the mesh whose slot block holds at least one of the
+        batch's members (1 without a mesh): members take slots 0..m-1, and
+        each device holds an equal run of consecutive slots."""
+        if self.mesh is None:
+            return 1
+        slots_per_chip = batch.slots // self.mesh.size
+        return -(-len(batch.queries) // slots_per_chip)
+
     def _slot_ids_for(self, batch: PlannedBatch, edge_ranges) -> np.ndarray:
         nnzp_total = batch.slots * batch.bucket.nnz_pad
         if batch.backend.layout == "aligned":
@@ -420,7 +429,11 @@ class Planner:
             )
         t0 = obs_clock.now()
         with tracer.span(
-            "pack", members=len(queries), slots=batch.slots, layout=backend.layout
+            "pack",
+            members=len(queries),
+            slots=batch.slots,
+            layout=backend.layout,
+            chips_used=self.chips_used(batch),
         ):
             packed = pack_problems(
                 [st.query.graph for st in queries],

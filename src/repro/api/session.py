@@ -557,6 +557,11 @@ class Session:
             len(batch) / planned.slots,
             buckets=(0.125, 0.25, 0.5, 0.75, 1.0),
         )
+        m.observe(
+            "batch_chips_used",
+            self.planner.chips_used(planned),
+            buckets=(1, 2, 4, 8),
+        )
         return results
 
     def _shed(self, state: QueryState, err: BaseException) -> None:

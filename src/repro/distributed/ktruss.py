@@ -10,10 +10,16 @@ cross-device triangle closing.  Vertex-dim arrays (``rowptr``, ``deg``,
 ``urowptr``, ``udeg``) stay replicated: they are O(n) index metadata, tiny
 next to the O(nnz·window) intersection state.
 
-Verified on CPU with ``XLA_FLAGS=--xla_force_host_platform_device_count=8``
-(see ``tests/test_exec_peel.py``): sharded results are bit-identical to
-unsharded — all peel state is integer/bool, so GSPMD's partitioning cannot
-introduce rounding differences.
+Sharded results are bit-identical to unsharded: all peel state is
+integer/bool, so GSPMD's partitioning cannot introduce rounding
+differences.  On a four-chip TPU v5e host (2x2) ``chip_smoke.py --chips 4``
+compares a sharded batch with the same batch on one chip, and the
+benchmark cell ``kron8-mesh4-serve`` serves Kronecker scale-8 3-truss
+queries through ``Session(mesh=slot_mesh(4), max_batch=32)`` at a fixed
+rate, every answer checked against the plain reference.  On the CPU,
+``tests/test_exec_peel.py`` and ``tests/test_mesh_serving.py`` run the
+path on virtual devices (``XLA_FLAGS=--xla_force_host_platform_device_count``)
+and ``tests/test_tpu_compile.py`` compiles it for a described ``v5e:2x2``.
 """
 
 from __future__ import annotations

@@ -506,12 +506,15 @@ class PeelExecutor:
             if self.mesh is not None:
                 from ..distributed.ktruss import shard_peel_args
 
-                (p, slot_ids, k0, single_level, alive0, frozen, frozen_truss) = (
-                    shard_peel_args(
-                        self.mesh, p, slot_ids, k0, single_level, alive0,
-                        frozen, frozen_truss,
+                # The batch was built on the default device; "shard" is the
+                # host's cost of re-placing it across the mesh.
+                with tracer.span("shard", chips=self.mesh.size):
+                    (p, slot_ids, k0, single_level, alive0, frozen, frozen_truss) = (
+                        shard_peel_args(
+                            self.mesh, p, slot_ids, k0, single_level, alive0,
+                            frozen, frozen_truss,
+                        )
                     )
-                )
             self.dispatches += 1
             current_registry().inc("peel_dispatches")
             st = self._peel(

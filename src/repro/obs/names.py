@@ -83,6 +83,7 @@ GAUGES: frozenset[str] = frozenset(
 
 HISTOGRAMS: frozenset[str] = frozenset(
     {
+        "batch_chips_used",  # mesh devices a batch's members occupy
         "batch_occupancy",
         "peel_batch_imbalance",
         "peel_device_time_s",

@@ -30,8 +30,10 @@ from repro.exec.peel import build_peel, make_problem_support, peel_arg_shapes
 # (coarse/xla) on 2 slots; many-small on 8.
 RMAT_BUCKET, ROAD_BUCKET, STATIC_SLOTS = (512, 4096, 256), (262144, 1048576, 8), 2
 SMALL_BUCKET, SMALL_SLOTS = (512, 4096, 32), 8
-# The benchmark's buckets: Kronecker scale 8 on 1 slot, scale 7 on 8.
+# The benchmark's buckets: Kronecker scale 8 on 1 slot, scale 7 on 8; and
+# scale 8 on 32 slots over the four chips of kron8-mesh4-serve.
 KRON8_BUCKET, KRON7_BUCKET = (256, 4096, 256), (128, 1024, 128)
+MESH_SLOTS = 32
 
 
 @pytest.fixture(scope="module")
@@ -114,16 +116,14 @@ def test_fine_eager_support_intersects_without_search_gathers(one_chip, bucket, 
     assert not [line for line in gathers if "take_along_axis" in line]
 
 
-def test_sharded_peel_compiles_for_v5e_2x2(topo):
+def _sharded_peel(topo, bucket, slots):
     mesh = Mesh(
         np.array(topo.devices),
         (SLOT_AXIS,),
         axis_types=(jax.sharding.AxisType.Auto,),
     )
-    n_pad, nnz_pad, window = SMALL_BUCKET
-    args = peel_arg_shapes(
-        n=SMALL_SLOTS * n_pad, nnz_pad=SMALL_SLOTS * nnz_pad, slots=SMALL_SLOTS
-    )
+    n_pad, nnz_pad, window = bucket
+    args = peel_arg_shapes(n=slots * n_pad, nnz_pad=slots * nnz_pad, slots=slots)
     compiled = (
         _peel_program("fine", "eager", window)
         .lower(*_placed(args, peel_arg_shardings(mesh)))
@@ -135,3 +135,13 @@ def test_sharded_peel_compiles_for_v5e_2x2(topo):
     assert p.colidx.spec == jax.sharding.PartitionSpec(SLOT_AXIS)
     assert slot_ids.spec == jax.sharding.PartitionSpec(SLOT_AXIS)
     assert "all-gather" not in compiled.as_text()
+    return compiled
+
+
+def test_sharded_peel_compiles_for_v5e_2x2(topo):
+    _sharded_peel(topo, SMALL_BUCKET, SMALL_SLOTS)
+
+
+def test_mesh_cell_peel_compiles_for_v5e_2x2(topo):
+    compiled = _sharded_peel(topo, KRON8_BUCKET, MESH_SLOTS)
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**30
