@@ -10,8 +10,20 @@ cross-device triangle closing.  Vertex-dim arrays (``rowptr``, ``deg``,
 ``urowptr``, ``udeg``) stay replicated: they are O(n) index metadata, tiny
 next to the O(nnz·window) intersection state.
 
+The fine eager support pass runs per device (:func:`per_chip_support`,
+under ``shard_map``): each device scans only its own lanes, with
+``rowptr`` rebased to its lane block.  That is exact because the kernel
+reads ``rowptr`` only as row starts, and the aligned packing keeps every
+row of a slot inside the slot's lane block, so every vertex a device's
+edges name has its row on that device; vertex ids stay global (indices
+into the replicated metadata and keys of the compare).  Under GSPMD alone
+the pass's ``lax.scan`` took its trip count from the global lane count,
+and every device ran every chunk of the mesh.  The prune and its per-slot
+bookkeeping stay GSPMD-partitioned, meeting across devices only in
+reductions.
+
 Sharded results are bit-identical to unsharded: all peel state is
-integer/bool, so GSPMD's partitioning cannot introduce rounding
+integer/bool, so neither partitioning can introduce rounding
 differences.  On a four-chip TPU v5e host (2x2) ``chip_smoke.py --chips 4``
 compares a sharded batch with the same batch on one chip, and the
 benchmark cell ``kron8-mesh4-serve`` serves Kronecker scale-8 3-truss
@@ -24,6 +36,8 @@ and ``tests/test_tpu_compile.py`` compiles it for a described ``v5e:2x2``.
 
 from __future__ import annotations
 
+from typing import Callable
+
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -34,6 +48,7 @@ __all__ = [
     "SLOT_AXIS",
     "slot_mesh",
     "peel_problem_specs",
+    "per_chip_support",
     "peel_arg_shardings",
     "shard_peel_args",
 ]
@@ -81,6 +96,35 @@ def peel_problem_specs() -> list[P]:
         edge,  # uedge_row(unnzp,)
         rep,  # udeg     (n+1,)
     ]
+
+
+def per_chip_support(
+    support: Callable[[FineProblem, jax.Array], jax.Array], mesh: Mesh
+) -> Callable[[FineProblem, jax.Array], jax.Array]:
+    """``support`` run on each device of ``mesh`` over its own lane block.
+
+    For a support that reads ``rowptr`` only as row starts and touches no
+    other lane-position field (the fine eager pass): each device sees its
+    ``nnz_pad / d`` edge lanes and the replicated vertex metadata, with
+    ``rowptr`` rebased by the block's first lane, so a lane position is
+    local.  Exact for slot-aligned packings whose slots shard whole (see
+    the module docstring).
+    """
+    edge = P(SLOT_AXIS)
+
+    def local_support(p: FineProblem, alive: jax.Array) -> jax.Array:
+        base = jax.lax.axis_index(SLOT_AXIS) * p.nnz_pad
+        return support(p._replace(rowptr=p.rowptr - base), alive)
+
+    return jax.shard_map(
+        local_support,
+        mesh=mesh,
+        in_specs=(FineProblem(*peel_problem_specs()), edge),
+        out_specs=edge,
+        # The support's scan starts from a replicated zero accumulator and
+        # carries per-device sums; the output is per-device by its spec.
+        check_vma=False,
+    )
 
 
 def peel_arg_shardings(mesh: Mesh) -> tuple:

@@ -20,8 +20,9 @@ packing is a disjoint union, each slot's fixed point is independent and a
 per-slot convergence test (``segment_sum`` of changed lanes) is exact.
 
 :class:`PeelExecutor` wraps the compiled peel with optional mesh placement
-(slot blocks sharded across devices — see ``repro.distributed.ktruss``)
-and a dispatch counter that tests use to assert the one-dispatch contract.
+(slot blocks sharded across devices, the fine eager support pass run per
+device — see ``repro.distributed.ktruss``) and a dispatch counter that
+tests use to assert the one-dispatch contract.
 """
 
 from __future__ import annotations
@@ -349,7 +350,15 @@ class PeelExecutor:
     its shapes — a single graph (one slot) or a packed batch.  With
     ``mesh=`` the packed slot blocks are sharded across devices before
     dispatch (slot boundaries are natural shard boundaries because the
-    block-diagonal packing makes slots independent).
+    block-diagonal packing makes slots independent).  On a mesh the
+    fine/xla eager support pass runs per device
+    (``repro.distributed.ktruss.per_chip_support``): each device scans its
+    own lanes, with ``rowptr`` rebased to its lane block — exact because
+    the pass reads ``rowptr`` only as row starts and every row of an
+    aligned slot lies in the slot's lanes.  ``support_per_chip`` says
+    whether it engaged; every other support, and the prune with its
+    per-slot bookkeeping, stays GSPMD-partitioned.  Without a mesh the
+    program is the plain :func:`build_peel` of the support.
 
     ``dispatches`` counts calls into the compiled peel; the serving layer
     and tests use it to assert the one-dispatch-per-batch contract.
@@ -388,6 +397,7 @@ class PeelExecutor:
             self.fused_config = cfg
             self.support = None
             self.mesh = None
+            self.support_per_chip = False
             self._level_step = make_fused_level(
                 window=window, block=cfg.block, schedule=cfg.schedule
             )
@@ -397,6 +407,7 @@ class PeelExecutor:
             )
             self.dispatches = 0
             return
+        per_chip = False
         if support is None:
             if window is None:
                 raise ValueError("window is required unless support= is given")
@@ -408,7 +419,17 @@ class PeelExecutor:
                 chunk=chunk,
                 row_chunk=row_chunk,
             )
+            # The one support known to read lane positions only through
+            # rowptr's row starts, which is what per-device rebasing needs.
+            per_chip = mesh is not None and (granularity, mode, backend) == (
+                "fine", "eager", "xla"
+            )
+        if per_chip:
+            from ..distributed.ktruss import per_chip_support
+
+            support = per_chip_support(support, mesh)
         self.support = support
+        self.support_per_chip = per_chip
         self.mesh = mesh
         self._peel = build_peel(support, max_iters=max_iters)
         self.dispatches = 0
@@ -517,6 +538,8 @@ class PeelExecutor:
                     )
             self.dispatches += 1
             current_registry().inc("peel_dispatches")
+            if self.support_per_chip:
+                current_registry().inc("peel_sharded_support_dispatches")
             st = self._peel(
                 p, slot_ids, k0, single_level, alive0, frozen, frozen_truss
             )

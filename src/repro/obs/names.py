@@ -28,6 +28,7 @@ COUNTERS: frozenset[str] = frozenset(
         "peel_batches",
         "peel_dispatches",
         "peel_fused_levels",
+        "peel_sharded_support_dispatches",  # dispatches whose support ran per chip
         "peel_slots",
         # compile cache
         "cache_bucket_compiles",

@@ -4,10 +4,12 @@ Covers the PR-level contracts: one device dispatch per decompose/kmax (no
 per-level host round-trips, asserted via the executor dispatch counter),
 batched trussness bit-identical to the per-graph engine across generator
 families, slot-aligned packing, the Pallas backend through the serving
-path, targeted ``result()`` resolution, and the sharded executor on 8
-simulated host devices matching unsharded results exactly.
+path, targeted ``result()`` resolution, the sharded executor on 8
+simulated host devices matching unsharded results exactly, and the support
+pass run per device on a 4-device slot mesh matching one device bit for bit.
 """
 
+import json
 import os
 import subprocess
 import sys
@@ -247,3 +249,157 @@ def test_sharded_peel_matches_unsharded_subprocess():
     )
     assert proc.returncode == 0, proc.stdout + proc.stderr
     assert "SHARDED_OK" in proc.stdout
+
+
+# ------------------------------------------------------------------ #
+# Per-chip support on a 4-device slot mesh == one chip, bit for bit
+# ------------------------------------------------------------------ #
+_PER_CHIP_SCRIPT = r"""
+import json
+
+import numpy as np
+import jax
+import jax.numpy as jnp
+
+from repro.core.reference import trussness_numpy
+from repro.distributed import slot_mesh
+from repro.distributed.ktruss import shard_peel_args
+from repro.exec import PeelExecutor
+from repro.graphs import from_edges, pack_problems, rmat
+from repro.obs import MetricsRegistry, use_registry
+
+assert len(jax.devices()) == 4, jax.devices()
+CHUNK, SLOTS, SLOT_N = 16, 8, 32
+
+
+def hub_graph(nnz, seed):
+    # Hub 15 (0-based) links up to 16..31, which link nowhere further, and
+    # down to 0..14, which also link to some of 16..31: the hub's row is the
+    # last non-empty row, so it ends on the member's last lane.
+    ups = [(15, a) for a in range(16, 32)]
+    downs = [(x, 15) for x in range(15)]
+    links = [(x, a) for x in range(15) for a in range(16, 32)]
+    pick = np.random.default_rng(seed).permutation(len(links))[: nnz - 31]
+    g = from_edges(32, np.array(ups + downs + [links[i] for i in pick]))
+    assert g.nnz == nnz and g.row_of_edge()[-1] == 16
+    return g
+
+
+rmats = [rmat(5, 8, seed=s) for s in range(4)]
+slot_nnz = -(-max(g.nnz for g in rmats) // CHUNK) * CHUNK
+hubs = [hub_graph(slot_nnz, seed=s) for s in range(4)]
+# Slots 1, 3, 5, 7 (each chip's last) hold a hub whose row ends on the
+# chip's last lane.
+full = [g for pair in zip(rmats, hubs) for g in pair]
+window = 1 << int(max(g.undirected_csr().degrees().max() for g in full) - 1).bit_length()
+mesh = slot_mesh(4)
+
+
+def batch(graphs, frozen_every):
+    pp = pack_problems(graphs, slot_n=SLOT_N, slot_nnz=slot_nnz, slots=SLOTS,
+                       chunk=CHUNK, layout="aligned")
+    real = np.zeros(SLOTS * slot_nnz, bool)
+    truss = np.zeros(SLOTS * slot_nnz, np.int32)
+    for g, (lo, hi) in zip(graphs, pp.edge_ranges):
+        real[lo:hi] = True
+        truss[lo:hi] = trussness_numpy(g)
+    frozen = np.zeros_like(real)
+    if frozen_every:
+        frozen = real & (np.arange(real.size) % frozen_every == 0)
+    return pp.problem, real & ~frozen, frozen, np.where(frozen, truss, 0)
+
+
+CASES = {
+    "ktruss3-frozen": ("eager", full, True, 5),
+    "decompose-frozen": ("eager", full, False, 5),
+    "ktruss3-empty-chips": ("eager", full[:2], True, 0),
+    "decompose-empty-chips": ("eager", full[:2], False, 0),
+    "owner-decompose-frozen": ("owner", full, False, 5),
+}
+executors = {
+    mode: {
+        "plain": PeelExecutor(mode=mode, window=window, chunk=CHUNK),
+        "mesh": PeelExecutor(mode=mode, window=window, chunk=CHUNK, mesh=mesh),
+    }
+    for mode in ("eager", "owner")
+}
+out = {}
+for name, (mode, graphs, single, frozen_every) in CASES.items():
+    p, alive0, frozen, frozen_truss = batch(graphs, frozen_every)
+    kw = dict(
+        slot_ids=np.repeat(np.arange(SLOTS), slot_nnz), k0=np.full(SLOTS, 3),
+        single_level=np.full(SLOTS, single), alive0=jnp.asarray(alive0),
+        frozen=jnp.asarray(frozen), frozen_truss=jnp.asarray(frozen_truss),
+    )
+    states, counts = {}, {}
+    for side, ex in executors[mode].items():
+        reg = MetricsRegistry()
+        with use_registry(reg):
+            states[side] = ex.peel(p, **kw)
+        counts[side] = [reg.value("peel_sharded_support_dispatches"),
+                        reg.value("peel_dispatches")]
+    # The support alone, on one chip and per chip, over the same lanes.
+    eff = jnp.asarray(alive0 | frozen)
+    plain_s = jax.jit(executors[mode]["plain"].support)(p, eff)
+    sp, *_, s_eff, _, _ = shard_peel_args(mesh, p, kw["slot_ids"], kw["k0"], kw["single_level"],
+                                          eff, kw["frozen"], kw["frozen_truss"])
+    mesh_s = jax.jit(executors[mode]["mesh"].support)(sp, s_eff)
+    a, b = states["mesh"], states["plain"]
+    out[name] = {
+        "fields_differ": [f for f in a._fields
+                          if not np.array_equal(np.asarray(getattr(a, f)), np.asarray(getattr(b, f)))],
+        "support_same": bool(np.array_equal(np.asarray(plain_s), np.asarray(mesh_s))),
+        "support_nonzero": int((np.asarray(plain_s) > 0).sum()),
+        "per_chip": executors[mode]["mesh"].support_per_chip,
+        "counts": counts,
+        "kmax": np.asarray(b.kmax).tolist(),
+    }
+print("PER_CHIP " + json.dumps(out))
+"""
+
+PER_CHIP_CASES = (
+    "ktruss3-frozen",
+    "decompose-frozen",
+    "ktruss3-empty-chips",
+    "decompose-empty-chips",
+    "owner-decompose-frozen",
+)
+
+
+@pytest.fixture(scope="module")
+def per_chip_runs():
+    """The cases of ``_PER_CHIP_SCRIPT``, peeled once in a fresh process on
+    4 simulated host devices (``XLA_FLAGS`` must precede JAX's start)."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env["XLA_FLAGS"] = (
+        "--xla_force_host_platform_device_count=4 " + env.get("XLA_FLAGS", "")
+    ).strip()
+    src = os.path.join(os.path.dirname(__file__), os.pardir, "src")
+    env["PYTHONPATH"] = os.path.abspath(src) + os.pathsep + env.get("PYTHONPATH", "")
+    proc = subprocess.run(
+        [sys.executable, "-c", _PER_CHIP_SCRIPT],
+        env=env,
+        capture_output=True,
+        text=True,
+        timeout=900,
+    )
+    assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-3000:]
+    [line] = [ln for ln in proc.stdout.splitlines() if ln.startswith("PER_CHIP ")]
+    return json.loads(line.split(" ", 1)[1])
+
+
+@pytest.mark.parametrize("case", PER_CHIP_CASES)
+def test_per_chip_support_matches_one_chip(per_chip_runs, case):
+    """The support run per chip over its own lanes (fine/xla eager), and the
+    GSPMD support it falls back to (owner), give one chip's peel bit for
+    bit: frozen lanes, hub rows ending on a chip's last lane, whole chips of
+    empty slots."""
+    run = per_chip_runs[case]
+    assert run["fields_differ"] == []
+    assert run["support_same"] and run["support_nonzero"] > 0
+    assert run["per_chip"] == (not case.startswith("owner"))
+    # One dispatch each side; the per-chip counter ticks only where it ran.
+    sharded = 1 if run["per_chip"] else 0
+    assert run["counts"] == {"mesh": [sharded, 1], "plain": [0, 1]}
+    if "empty-chips" in case:
+        assert run["kmax"][2:] == [0] * 6

@@ -6,7 +6,9 @@ in batches of 1, 9 and 32 members, for ``ktruss(3)`` and ``decompose``;
 every answer must equal the numpy oracle exactly.  The traced session must
 show one ``shard`` span per batch (the arguments placed across the four
 devices) and a ``batch_chips_used`` of 1, 2 and 4 for those fills (8 slots
-a device).  A session without a mesh uses one chip and shards nothing.
+a device), and every dispatch runs the support pass per chip
+(``peel_sharded_support_dispatches``).  A session without a mesh uses one
+chip, shards nothing and runs no per-chip support.
 
 The mesh needs four devices before JAX starts, so the serving runs once,
 in a fresh process with ``--xla_force_host_platform_device_count=4``, and
@@ -92,6 +94,11 @@ out["shard_inside_dispatch"] = all(
 plain = strict_session()
 out["meshless"] = serve(plain, "ktruss", graphs[:9])
 out["meshless"]["shard"] = sum(ev["name"] == "shard" for ev in plain.obs.tracer.events())
+out["per_chip_support"] = {
+    side: [s.obs.metrics.value(name)
+           for name in ("peel_sharded_support_dispatches", "peel_dispatches")]
+    for side, s in (("mesh", mesh_session), ("meshless", plain))
+}
 print("MESH_SERVING " + json.dumps(out))
 """
 
@@ -135,6 +142,15 @@ def test_batch_chips_used_counts_the_occupied_chips(served):
     for workload in WORKLOADS:
         used = [served["mesh"][f"{workload}-{fill}"]["chips_used"] for fill in FILLS]
         assert used == [[1, 1.0], [1, 2.0], [1, 4.0]]
+
+
+def test_per_chip_support_ticks_once_per_mesh_dispatch(served):
+    # The mesh session's fine/xla eager batches run the support per chip,
+    # each dispatch once; a session without a mesh never does.
+    mesh_sharded, mesh_dispatches = served["per_chip_support"]["mesh"]
+    assert mesh_dispatches == len(WORKLOADS) * len(FILLS)
+    assert mesh_sharded == mesh_dispatches
+    assert served["per_chip_support"]["meshless"] == [0, 1]
 
 
 def test_meshless_session_uses_one_chip_and_shards_nothing(served):

@@ -15,6 +15,7 @@ entry compiled for a described chip cannot be read back without one.
 """
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -23,7 +24,7 @@ import jax
 from jax.sharding import Mesh, SingleDeviceSharding
 
 from repro.distributed.ktruss import SLOT_AXIS, peel_arg_shardings
-from repro.exec.peel import build_peel, make_problem_support, peel_arg_shapes
+from repro.exec.peel import PeelExecutor, build_peel, make_problem_support, peel_arg_shapes
 
 # Buckets (n_pad, nnz_pad, window) and slot counts of chip_smoke.py's
 # phases: large-static rmat-9 (the auto rule picks fine/xla) and road-512
@@ -116,19 +117,39 @@ def test_fine_eager_support_intersects_without_search_gathers(one_chip, bucket, 
     assert not [line for line in gathers if "take_along_axis" in line]
 
 
+def _support_scan_bounds(text):
+    """Trip counts of the support pass's chunk loops in a compiled program:
+    the constant each ``while/cond`` compare inside the ``support`` scope
+    tests the chunk counter against."""
+    consts = dict(re.findall(r"%(\S+) = s32\[\]\S* constant\((\d+)\)", text))
+    cond = r'compare\(%\S+, %(\S+)\), direction=LT, metadata=\{op_name="[^"]*/support/[^"]*while/cond/'
+    return [int(consts[name]) for name in re.findall(cond, text)]
+
+
+def _without_source_locations(text):
+    """A compiled program's text less what names the Python source that
+    built it: the same program built at two call sites reads the same."""
+    lines = (re.sub(r", metadata=\{[^}]*\}", "", line) for line in text.splitlines())
+    return [
+        line
+        for line in lines
+        if not re.match(r"\d+ |FileNames|FunctionNames|FileLocations|StackFrames", line)
+    ]
+
+
 def _sharded_peel(topo, bucket, slots):
+    # The program the executor builds for a mesh session: the support pass
+    # run per chip, the prune partitioned by GSPMD.
     mesh = Mesh(
         np.array(topo.devices),
         (SLOT_AXIS,),
         axis_types=(jax.sharding.AxisType.Auto,),
     )
     n_pad, nnz_pad, window = bucket
-    args = peel_arg_shapes(n=slots * n_pad, nnz_pad=slots * nnz_pad, slots=slots)
-    compiled = (
-        _peel_program("fine", "eager", window)
-        .lower(*_placed(args, peel_arg_shardings(mesh)))
-        .compile()
-    )
+    executor = PeelExecutor(window=window, chunk=256, mesh=mesh)
+    assert executor.support_per_chip
+    executor.compile(n=slots * n_pad, nnz_pad=slots * nnz_pad, slots=slots)
+    compiled = executor._peel
     # Slot blocks stay sharded: every edge-lane input keeps its "slots"
     # partitioning, and the devices meet only in reductions.
     (p, slot_ids, *_rest), _kwargs = compiled.input_shardings
@@ -138,10 +159,38 @@ def _sharded_peel(topo, bucket, slots):
     return compiled
 
 
+@pytest.fixture(scope="module")
+def mesh_cell_peel(topo):
+    return _sharded_peel(topo, KRON8_BUCKET, MESH_SLOTS)
+
+
 def test_sharded_peel_compiles_for_v5e_2x2(topo):
     _sharded_peel(topo, SMALL_BUCKET, SMALL_SLOTS)
 
 
-def test_mesh_cell_peel_compiles_for_v5e_2x2(topo):
-    compiled = _sharded_peel(topo, KRON8_BUCKET, MESH_SLOTS)
-    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**30
+def test_mesh_cell_peel_compiles_for_v5e_2x2(mesh_cell_peel):
+    assert mesh_cell_peel.memory_analysis().temp_size_in_bytes < 16 * 2**30
+
+
+def test_mesh_cell_support_scans_each_chips_own_chunks(mesh_cell_peel):
+    # 32 slots of 4,096 lanes over four chips: each chip's chunk loop runs
+    # over its own 8 slots' 128 chunks of 256 lanes, not the mesh's 512.
+    _n_pad, nnz_pad, _window = KRON8_BUCKET
+    chunks_per_chip = MESH_SLOTS // 4 * nnz_pad // 256
+    assert _support_scan_bounds(mesh_cell_peel.as_text()) == [chunks_per_chip]
+
+
+def test_one_chip_peel_is_the_plain_build(one_chip):
+    # Without a mesh the executor compiles the plain build_peel of its
+    # support: the one-chip cells run the program they ran before.
+    n_pad, nnz_pad, window = KRON8_BUCKET
+    args = peel_arg_shapes(n=n_pad, nnz_pad=nnz_pad, slots=1)
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), args
+    )
+    executor = PeelExecutor(window=window, chunk=256)
+    assert not executor.support_per_chip
+    text = executor._peel.lower(*args).compile().as_text()
+    plain = _peel_program("fine", "eager", window).lower(*args).compile().as_text()
+    assert _without_source_locations(text) == _without_source_locations(plain)
+    assert _support_scan_bounds(text) == [nnz_pad // 256]
