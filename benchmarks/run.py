@@ -71,14 +71,13 @@ def main() -> None:
         print("paper_reference,CPU 1.48x / GPU 16.93x (K=3)")
 
     if only in (None, "kernels"):
-        _section("kernels (structural + interpret + fused megakernel)")
+        _section("kernels (structural + interpret)")
         from . import kernels_bench
 
         for r in kernels_bench.kernel_structure_rows():
             print(r)
         for r in kernels_bench.run_kernel_bench():
             print(r)
-        kernels_bench.report(kernels_bench.run_fused_bench(smoke=True))
 
     if only in (None, "service"):
         _section("service (batched serving: graphs/s + cache hit rate)")

@@ -157,7 +157,6 @@ class AnalysisConfig:
             files=files,
             trace_files=[
                 "src/repro/exec/peel.py",
-                "src/repro/kernels/peel_fused.py",
                 "src/repro/core/eager_fine.py",
             ],
             dispatch_files=[

@@ -77,11 +77,6 @@ def enable_persistent_cache(cache_dir: str | None = None) -> str:
     The entry-size/compile-time floors are dropped to 0 so even the small
     CPU-test executables round-trip (JAX's defaults skip sub-second
     compiles, which would make a warm restart silently cold).
-
-    The fused megakernel's autotune store rides along: winning per-bucket
-    kernel configs persist to ``<cache_dir>/autotune.json`` and are
-    replayed on warm start (``repro.kernels.autotune.lookup`` — the
-    planner consults it whenever it builds a fused executor).
     """
     import jax
 
@@ -89,10 +84,6 @@ def enable_persistent_cache(cache_dir: str | None = None) -> str:
     jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-
-    from ..kernels import autotune
-
-    autotune.set_store(os.path.join(cache_dir, "autotune.json"))
     return cache_dir
 
 

@@ -185,15 +185,6 @@ class Planner:
                 f"backend {self.backend} has layout={self.backend.layout!r}, "
                 "but mesh sharding needs layout='aligned'"
             )
-        if (
-            mesh is not None
-            and self.backend is not None
-            and self.backend.kernel == "fused"
-        ):
-            raise ValueError(
-                f"backend {self.backend} keeps peel state kernel-resident "
-                "and cannot shard across a mesh; use fine/pallas/aligned"
-            )
         # Observability + shared caches.  Concurrent submitters (the
         # serving tier's connection threads) all assign through one
         # planner, so everything mutable below is lock-guarded.
@@ -225,11 +216,6 @@ class Planner:
                     kernel=self.kernel,
                     layout=self.layout,
                 )
-                if self.mesh is not None and key.kernel == "fused":
-                    # The auto rule upgraded to the kernel-resident
-                    # megakernel, but a mesh session must shard: step
-                    # down to the unfused Pallas twin (bit-identical).
-                    key = BackendKey(key.formulation, "pallas", key.layout)
             else:
                 key = get_backend(key).key
             if self.mesh is not None and key.layout != "aligned":
@@ -239,11 +225,6 @@ class Planner:
                 raise ValueError(
                     f"backend {key} has layout={key.layout!r}, but mesh "
                     "sharding needs layout='aligned'"
-                )
-            if self.mesh is not None and key.kernel == "fused":
-                raise ValueError(
-                    f"backend {key} keeps peel state kernel-resident and "
-                    "cannot shard across a mesh; use fine/pallas/aligned"
                 )
             span.attrs["backend"] = str(key)
         dt = obs_clock.now() - t0
@@ -291,12 +272,7 @@ class Planner:
     # ------------------------------------------------------------------ #
     # Lowering: batch -> one device dispatch -> per-query results
     # ------------------------------------------------------------------ #
-    def cache_variant(
-        self,
-        backend: BackendKey,
-        bucket: Bucket | None = None,
-        slots: int | None = None,
-    ):
+    def cache_variant(self, backend: BackendKey):
         """What beyond (bucket, slots) specializes the executable.
 
         Every planner attribute ``build_executor`` closes over MUST be
@@ -304,32 +280,14 @@ class Planner:
         a closed-over scalar missing from this tuple is a recompile
         hazard: two configs would share one cache row and the second
         would silently reuse the first's executable.  The R2 lint
-        (``repro.analysis.rules_recompile``) enforces the invariant.
-
-        Fused backends additionally fold the bucket's autotuned kernel
-        config (``repro.kernels.autotune.lookup``) into the key, so a
-        newly tuned block/schedule compiles its own executable instead
-        of silently reusing a stale one."""
-        fused_sig = None
-        if backend.kernel == "fused" and bucket is not None:
-            cfg = self.fused_config_for(bucket, slots or self.max_batch)
-            fused_sig = cfg.signature()
+        (``repro.analysis.rules_recompile``) enforces the invariant."""
         return (
             backend,
             self.mode,
             self._mesh_key,
             self.chunk,
             self.max_iters,
-            fused_sig,
         )
-
-    def fused_config_for(self, bucket: Bucket, slots: int):
-        """The fused tuning point for one (bucket, slots): the persisted
-        autotune winner when one exists, the stock default otherwise —
-        always clamped so the block divides the bucket's slot width."""
-        from ..kernels import autotune
-
-        return autotune.lookup(bucket, slots).clamp(bucket.nnz_pad)
 
     def build_executor(self, key: tuple[Bucket, int, Any]):
         """Compile-cache builder: one peel executor per cache key,
@@ -342,19 +300,13 @@ class Planner:
         arrive through the variant tuple (see :meth:`cache_variant`).
         ``self.mesh`` is the one closed-over object (unhashable), keyed
         by its ``_mesh_key`` fold."""
-        bucket, slots, (backend, mode, _mesh_key, chunk, max_iters, fused_sig) = key
-        fused_config = None
-        if fused_sig is not None:
-            from ..kernels.autotune import FusedConfig
-
-            fused_config = FusedConfig.from_signature(fused_sig)
+        bucket, slots, (backend, mode, _mesh_key, chunk, max_iters) = key
         exe = get_backend(backend).make_executor(
             window=bucket.window,
             chunk=chunk,
             max_iters=max_iters,
             mesh=self.mesh,
             mode=mode,
-            fused_config=fused_config,
         )
         exe.compile(
             n=slots * bucket.n_pad, nnz_pad=slots * bucket.nnz_pad, slots=slots
@@ -447,7 +399,7 @@ class Planner:
         with tracer.span("compile", backend=str(backend)) as span:
             inject("compile", bucket=bucket, backend=str(backend), queries=qids)
             exe, hit = cache.get(
-                bucket, batch.slots, self.cache_variant(backend, bucket, batch.slots)
+                bucket, batch.slots, self.cache_variant(backend)
             )
             span.attrs["hit"] = hit
         for st in queries:

@@ -9,17 +9,14 @@ choice a first-class, swappable backend axis instead of a constructor
 flag smeared across entry points:
 
 * ``formulation`` — ``coarse`` (row tasks) | ``fine`` (nonzero tasks);
-* ``kernel``      — ``xla`` (fused scatter/gather ops; the default on
-                    every platform) | ``pallas`` (hand-written TPU kernels,
-                    interpret-mode off the TPU) | ``fused`` (persistent
-                    Pallas peel megakernel: one launch per truss level,
-                    autotuned per bucket).  Neither Pallas kernel lowers
-                    for a TPU at the windows the auto rule produces, so
-                    both are reached only when forced;
+* ``kernel``      — ``xla`` (XLA scatter/gather ops; the default on
+                    every platform) | ``pallas`` (the hand-written per-step
+                    TPU support kernel, interpret-mode off the TPU).  The
+                    Pallas kernel does not lower for a TPU at the windows
+                    the auto rule produces, so it is reached only when
+                    forced;
 * ``layout``      — ``contig`` (prefix-sum packed lanes) | ``aligned``
-                    (slot-aligned lanes, shardable across a mesh; the
-                    only layout whose slot-banded lane geometry the fused
-                    megakernel can tile).
+                    (slot-aligned lanes, shardable across a mesh).
 
 Every registered backend is *semantically identical* — bit-identical
 ``trussness`` on any graph (parity-tested in ``tests/test_api.py``) — so
@@ -50,7 +47,7 @@ __all__ = [
 ]
 
 FORMULATIONS = ("coarse", "fine")
-KERNELS = ("xla", "pallas", "fused")
+KERNELS = ("xla", "pallas")
 LAYOUTS = ("contig", "aligned")
 
 
@@ -88,13 +85,10 @@ class BackendSpec:
         max_iters: int | None = None,
         mesh=None,
         mode: str | None = None,
-        fused_config=None,
     ):
         """Build this backend's :class:`repro.exec.PeelExecutor` for one
         shape bucket.  ``mode`` overrides the spec's dataflow (the legacy
-        ``TrussService(mode=...)`` knob); ``fused_config`` is the
-        ``kernel="fused"`` tuning point (``repro.kernels.autotune``),
-        ignored by the other kernels."""
+        ``TrussService(mode=...)`` knob)."""
         from ..exec.peel import PeelExecutor  # lazy: registry stays import-light
 
         return PeelExecutor(
@@ -106,7 +100,6 @@ class BackendSpec:
             row_chunk=row_chunk,
             max_iters=max_iters,
             mesh=mesh,
-            fused_config=fused_config,
         )
 
 
@@ -156,12 +149,11 @@ def available_backends() -> tuple[BackendKey, ...]:
 def default_kernel() -> str:
     """XLA on every platform.
 
-    The Pallas kernels stay off the auto path until they lower for a TPU
-    at the windows the auto rule gives them: Mosaic refuses the fused
-    kernel's 1-D vector gathers and the ``bsearch`` schedule's
-    ``take_along_axis``, and the ``compare`` schedule's (T, W, 128)
-    intermediate overflows scoped VMEM above tile=128, W=128.  Forcing
-    either kernel on a TPU raises a typed ``CompileError`` when the
+    The Pallas kernel stays off the auto path until it lowers for a TPU
+    at the windows the auto rule gives it: Mosaic refuses the ``bsearch``
+    schedule's ``take_along_axis``, and the ``compare`` schedule's
+    (T, W, 128) intermediate overflows scoped VMEM above tile=128,
+    W=128.  Forcing it on a TPU raises a typed ``CompileError`` when the
     bucket's executor is built (``Planner.build_executor``).
     """
     return "xla"
@@ -185,27 +177,13 @@ def choose_backend(
       coarse  iff  coarse_lane_efficiency >= 0.4 and coarse_imbalance <= 2.5
 
     (the road-network regime, where the paper measures fine/coarse ≈ 1×),
-    otherwise fine.  The Pallas and fused kernels
-    implement the fine formulation only, so ``kernel="pallas"`` or
-    ``"fused"`` forces ``fine``.  On the forced hand-kernel path
-    (``kernel="pallas"``) a *heavily* imbalanced bucket
-    (``coarse_imbalance > 8``) is upgraded to the fused megakernel when
-    its aligned variant is registered: a heavy degree tail means long
-    peel tails with mostly-dead lanes, which is exactly the regime the
-    fused kernel's dead-tile skipping pays in (its per-bucket autotuned
-    configs come from ``repro.kernels.autotune``).  Every backend returns
+    otherwise fine.  The Pallas kernel implements the fine formulation
+    only, so ``kernel="pallas"`` forces ``fine``.  Every backend returns
     identical results, so a wrong guess costs time, never correctness.
     """
     kernel = kernel or default_kernel()
     balanced = stats.coarse_lane_efficiency >= 0.4 and stats.coarse_imbalance <= 2.5
-    formulation = "coarse" if (balanced and kernel not in ("pallas", "fused")) else "fine"
-    if (
-        kernel == "pallas"
-        and layout == "aligned"
-        and stats.coarse_imbalance > 8.0
-        and BackendKey("fine", "fused", layout) in _REGISTRY
-    ):
-        kernel = "fused"
+    formulation = "coarse" if (balanced and kernel != "pallas") else "fine"
     key = BackendKey(formulation, kernel, layout)
     if key not in _REGISTRY:
         raise KeyError(f"auto-chosen backend {key} is not registered")
@@ -221,21 +199,16 @@ def fallback_backends(key: Union[BackendKey, str, tuple]) -> tuple[BackendKey, .
     at a time and **preserves the layout** (a mesh session requires
     ``aligned``; re-packing stays shape-compatible):
 
-    1. ``fused -> pallas`` — same formulation, same layout: the
-       megakernel that fails to build still has the unfused per-step
-       Pallas twin;
-    2. ``pallas -> xla`` — same formulation, same layout: a hand-written
+    1. ``pallas -> xla`` — same formulation, same layout: a hand-written
        kernel that fails to build still has the XLA-ops twin;
-    3. ``fine -> coarse`` on ``xla`` — the row-task formulation as the
+    2. ``fine -> coarse`` on ``xla`` — the row-task formulation as the
        last resort (slower under imbalance, but always compilable).
 
     Only registered keys are returned, and never ``key`` itself.
     """
     key = get_backend(key).key
     chain: list[BackendKey] = []
-    if key.kernel == "fused":
-        chain.append(BackendKey(key.formulation, "pallas", key.layout))
-    if key.kernel in ("pallas", "fused"):
+    if key.kernel == "pallas":
         chain.append(BackendKey(key.formulation, "xla", key.layout))
     if key.formulation == "fine":
         chain.append(BackendKey("coarse", "xla", key.layout))
@@ -265,20 +238,5 @@ def _register_defaults() -> None:
                 description="nonzero tasks, collision-free Pallas TPU kernel",
             )
         )
-    # The fused megakernel tiles the aligned layout's slot-banded lane
-    # geometry; there is no contig variant (a contig pack interleaves
-    # members' lanes, which its per-slot reductions cannot reshape).
-    register_backend(
-        BackendSpec(
-            key=BackendKey("fine", "fused", "aligned"),
-            mode="owner",
-            description=(
-                "persistent fused Pallas peel megakernel: support + prune + "
-                "level bookkeeping in one launch per level, autotuned per "
-                "bucket (repro.kernels.autotune)"
-            ),
-        )
-    )
-
 
 _register_defaults()

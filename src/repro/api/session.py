@@ -452,9 +452,7 @@ class Session:
         exe, _ = self.cache.get(
             bucket,
             self.planner.max_batch,
-            self.planner.cache_variant(
-                self.planner.backend, bucket, self.planner.max_batch
-            ),
+            self.planner.cache_variant(self.planner.backend),
         )
         return exe
 

@@ -45,7 +45,6 @@ __all__ = [
     "init_peel_state",
     "peel_arg_shapes",
     "build_peel",
-    "build_fused_peel",
     "PeelExecutor",
 ]
 
@@ -84,11 +83,6 @@ def make_problem_support(
     graph, so one compiled peel serves every same-bucket problem —
     including block-diagonal batches of them.
     """
-    if backend == "fused":
-        raise ValueError(
-            "the fused backend is not a support fn; it is built whole via "
-            "build_fused_peel (one megakernel launch per level)"
-        )
     if backend == "pallas":
         from ..kernels import ops as kernel_ops  # lazy: keeps exec dep-light
 
@@ -126,11 +120,9 @@ def init_peel_state(
     frozen: jax.Array,
     frozen_truss: jax.Array,
 ) -> PeelState:
-    """The peel's starting carry — shared by the unfused while-loop peel
-    (traced inside its jit) and the fused per-level path (built eagerly on
-    the host side of its level loop).  Frozen lanes carry their known
-    trussness straight through to the output; free lanes start at the
-    vacuous floor."""
+    """The peel's starting carry (traced inside :func:`build_peel`'s jit).
+    Frozen lanes carry their known trussness straight through to the
+    output; free lanes start at the vacuous floor."""
     num_slots = int(k0.shape[0])
     seg = functools.partial(jax.ops.segment_sum, num_segments=num_slots)
     edges0 = seg(alive0.astype(jnp.int32), slot_ids)
@@ -294,54 +286,6 @@ def build_peel(
     return jax.jit(peel)
 
 
-def build_fused_peel(
-    level_step: Callable, *, max_iters: int | None = None
-) -> Callable:
-    """Host-driven fused peel: one Pallas megakernel launch per level.
-
-    Same signature and bit-identical results as :func:`build_peel`'s
-    callable, but the support→prune fixed point of each level runs
-    entirely inside one persistent kernel — ``level_step``, built by
-    ``repro.kernels.peel_fused.make_fused_level`` — and the host loop
-    steps levels — emitting one ``"peel-level"`` span and one
-    ``peel_fused_levels`` counter tick per launch so traces show one
-    kernel per level.  A fired iteration cap returns the un-done state;
-    :meth:`PeelExecutor.peel`'s all-done belt raises the typed
-    ``DeviceError`` exactly as on the unfused path.
-    """
-
-    def peel(
-        p: FineProblem,
-        slot_ids: jax.Array,
-        k0: jax.Array,
-        single_level: jax.Array,
-        alive0: jax.Array,
-        frozen: jax.Array,
-        frozen_truss: jax.Array,
-    ) -> PeelState:
-        num_slots = int(k0.shape[0])
-        limit = (
-            int(alive0.shape[0]) + p.n + 4 if max_iters is None else int(max_iters)
-        )
-        state = init_peel_state(
-            p, slot_ids, k0, single_level, alive0, frozen, frozen_truss
-        )
-        tracer = current_tracer()
-        registry = current_registry()
-        level = 0
-        while not bool(np.asarray(state.done).all()):
-            if int(state.total_iters) >= limit:
-                break  # the executor's all-done belt raises DeviceError
-            with tracer.span("peel-level", level=level, slots=num_slots):
-                state = level_step(p, state, frozen, frozen_truss, single_level)
-                jax.block_until_ready(state.done)
-            registry.inc("peel_fused_levels")
-            level += 1
-        return state
-
-    return peel
-
-
 class PeelExecutor:
     """Unified executor for every multi-level K-truss workload.
 
@@ -376,37 +320,7 @@ class PeelExecutor:
         max_iters: int | None = None,
         mesh=None,
         support: Callable[[FineProblem, jax.Array], jax.Array] | None = None,
-        fused_config=None,
     ):
-        self.backend = backend
-        self.fused_config = None
-        if backend == "fused":
-            if mesh is not None:
-                raise ValueError(
-                    "the fused backend keeps peel state kernel-resident and "
-                    "does not shard; use fine/pallas/aligned under a mesh"
-                )
-            if granularity != "fine":
-                raise ValueError("fused backend implements the fine granularity")
-            if window is None:
-                raise ValueError("window is required for the fused backend")
-            from ..kernels.autotune import FusedConfig  # lazy: dep-light
-            from ..kernels.peel_fused import make_fused_level
-
-            cfg = fused_config if fused_config is not None else FusedConfig()
-            self.fused_config = cfg
-            self.support = None
-            self.mesh = None
-            self.support_per_chip = False
-            self._level_step = make_fused_level(
-                window=window, block=cfg.block, schedule=cfg.schedule
-            )
-            # Late-bound so compile() can swap in the ahead-of-time build.
-            self._peel = build_fused_peel(
-                lambda *args: self._level_step(*args), max_iters=max_iters
-            )
-            self.dispatches = 0
-            return
         per_chip = False
         if support is None:
             if window is None:
@@ -442,25 +356,6 @@ class PeelExecutor:
         shapes run the compiled program; other shapes are refused.
         """
         args = peel_arg_shapes(n=n, nnz_pad=nnz_pad, slots=slots)
-        if self.backend == "fused":
-            p, _slot_ids, k0, single_level, alive0, frozen, frozen_truss = args
-            # Per-lane and per-slot fields reuse those arguments' shapes.
-            state = PeelState(
-                alive=alive0,
-                support=frozen_truss,
-                trussness=frozen_truss,
-                cur_k=k0,
-                kmax=k0,
-                levels=k0,
-                iters=k0,
-                done=single_level,
-                total_iters=jax.ShapeDtypeStruct((), jnp.int32),
-                edges_alive=k0,
-            )
-            self._level_step = self._level_step.lower(
-                p, state, frozen, frozen_truss, single_level
-            ).compile()
-            return
         if self.mesh is not None:
             from ..distributed.ktruss import peel_arg_shardings
 
@@ -515,15 +410,6 @@ class PeelExecutor:
                 frozen = jnp.zeros(alive0.shape, bool)
             if frozen_truss is None:
                 frozen_truss = jnp.zeros(alive0.shape, jnp.int32)
-            if self.backend == "fused":
-                # The megakernel tiles lanes by `block` and reduces per-slot
-                # by reshaping to (slots, slot_nnz): refuse mis-tiled packs
-                # loudly (typed, slot-attributed) instead of mixing members.
-                from ..graphs.pack import validate_fused_tiling
-
-                validate_fused_tiling(
-                    p, slots=num_slots, block=self.fused_config.block
-                )
             if self.mesh is not None:
                 from ..distributed.ktruss import shard_peel_args
 

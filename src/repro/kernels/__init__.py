@@ -2,25 +2,17 @@
 
 ``support_fine``  — fine-grained edge-tile intersection kernel (Alg. 3).
 ``support_dense`` — blocked (U@U)∘U MXU kernel (Alg. 1).
-``peel_fused``    — persistent peel megakernel: support + prune + level
-                    bookkeeping fused into one launch per truss level.
-``autotune``      — per-bucket config sweep/store for the fused kernel.
 Validated in interpret mode against ``ref.py`` on CPU; written for TPU
 (BlockSpec VMEM tiling, MXU dots, VPU compare-reduce schedules).
 """
 
-from . import autotune, ops, ref
-from .autotune import FusedConfig
-from .peel_fused import make_fused_level
+from . import ops, ref
 from .support_dense import support_dense_pallas
 from .support_fine import support_fine_pallas
 
 __all__ = [
-    "autotune",
     "ops",
     "ref",
-    "FusedConfig",
-    "make_fused_level",
     "support_dense_pallas",
     "support_fine_pallas",
 ]

@@ -27,7 +27,6 @@ COUNTERS: frozenset[str] = frozenset(
         "dispatches",
         "peel_batches",
         "peel_dispatches",
-        "peel_fused_levels",
         "peel_sharded_support_dispatches",  # dispatches whose support ran per chip
         "peel_slots",
         # compile cache

@@ -138,17 +138,14 @@ def test_api_surface_snapshot():
 
 def test_default_backends_registered():
     keys = available_backends()
-    # coarse×pallas/fused is invalid (the hand kernels are fine-only) and
-    # fused×contig is invalid (the megakernel tiles the aligned layout's
-    # slot bands); every other point of the grid is registered for both
-    # layouts.
-    assert len(keys) == 7
+    # coarse×pallas is invalid (the hand kernel is fine-only); every other
+    # point of the grid is registered for both layouts.
+    assert len(keys) == 6
     assert BackendKey("fine", "xla", "aligned") in keys
     assert BackendKey("coarse", "xla", "contig") in keys
-    assert BackendKey("fine", "fused", "aligned") in keys
-    assert BackendKey("fine", "fused", "contig") not in keys
+    assert BackendKey("fine", "pallas", "aligned") in keys
     assert BackendKey("coarse", "pallas", "aligned") not in keys
-    assert BackendKey("coarse", "fused", "aligned") not in keys
+    assert BackendKey("fine", "fused", "aligned") not in keys
 
 
 # ------------------------------------------------------------------ #

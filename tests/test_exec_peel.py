@@ -4,7 +4,8 @@ Covers the PR-level contracts: one device dispatch per decompose/kmax (no
 per-level host round-trips, asserted via the executor dispatch counter),
 batched trussness bit-identical to the per-graph engine across generator
 families, slot-aligned packing, the Pallas backend through the serving
-path, targeted ``result()`` resolution, the sharded executor on 8
+path, every support giving the eager XLA support's whole peel state,
+targeted ``result()`` resolution, the sharded executor on 8
 simulated host devices matching unsharded results exactly, and the support
 pass run per device on a 4-device slot mesh matching one device bit for bit.
 """
@@ -19,7 +20,7 @@ import pytest
 
 import jax.numpy as jnp
 
-from repro.core import KTrussEngine, support_fine_eager, support_numpy
+from repro.core import KTrussEngine, support_fine_eager, support_numpy, trussness_numpy
 from repro.exec import PeelExecutor
 from repro.graphs import barabasi, clustered, erdos, pack_problems, rmat, road
 from repro.service import TrussService, bucket_for
@@ -198,6 +199,76 @@ def test_pallas_service_matches_xla_service():
     assert dec_p.kmax == dec_x.kmax and dec_p.levels == dec_x.levels
     assert np.array_equal(kt_p.alive, kt_x.alive)
     assert np.array_equal(kt_p.support, kt_x.support)
+
+
+# ------------------------------------------------------------------ #
+# Every support gives the eager XLA support's whole PeelState
+# ------------------------------------------------------------------ #
+_STATE_FIELDS = (
+    "alive", "support", "trussness", "cur_k", "kmax",
+    "levels", "iters", "done", "edges_alive",
+)
+
+
+def _packed_batch(graphs, chunk=64):
+    buckets = [bucket_for(g, chunk=chunk) for g in graphs]
+    n_pad = max(b.n_pad for b in buckets)
+    nnz_pad = max(b.nnz_pad for b in buckets)
+    window = max(b.window for b in buckets)
+    slots = len(graphs)
+    packed = pack_problems(
+        graphs,
+        slot_n=n_pad,
+        slot_nnz=nnz_pad,
+        slots=slots,
+        chunk=chunk,
+        layout="aligned",
+    )
+    slot_ids = np.repeat(np.arange(slots, dtype=np.int32), nnz_pad)
+    return packed, slot_ids, window
+
+
+def _assert_states_equal(st_a, st_b):
+    for field in _STATE_FIELDS:
+        a = np.asarray(getattr(st_a, field))
+        b = np.asarray(getattr(st_b, field))
+        assert np.array_equal(a, b), f"{field}: {a} != {b}"
+
+
+@pytest.mark.parametrize("frozen_half", [False, True], ids=["plain", "frozen"])
+@pytest.mark.parametrize(
+    "support",
+    [("fine", "owner", "xla"), ("coarse", "eager", "xla"), ("fine", "owner", "pallas")],
+    ids=["fine-owner-xla", "coarse-eager-xla", "fine-owner-pallas"],
+)
+def test_supports_give_identical_peel_state(support, frozen_half):
+    """Each support under the one peel program ends on exactly the state
+    fine/eager/xla ends on — per-slot iteration and level trajectories
+    included — from scratch and with half the real lanes frozen at the
+    oracle's trussness (the streaming form)."""
+    graphs = [rmat(5, 6, seed=1), barabasi(30, 3, seed=0)]
+    packed, slot_ids, window = _packed_batch(graphs)
+    p = packed.problem
+    kwargs = dict(slot_ids=slot_ids, k0=np.full(packed.slots, 3, np.int32))
+    if frozen_half:
+        real = np.asarray(p.colidx) != 0
+        frozen = real & (np.arange(real.shape[0]) % 2 == 0)
+        frozen_truss = np.zeros(real.shape[0], np.int32)
+        for g, (a, b) in zip(graphs, packed.edge_ranges):
+            frozen_truss[a:b] = np.where(frozen[a:b], trussness_numpy(g), 0)
+        kwargs.update(alive0=real & ~frozen, frozen=frozen, frozen_truss=frozen_truss)
+
+    ref = PeelExecutor(
+        granularity="fine", mode="eager", backend="xla", window=window, chunk=64
+    ).peel(p, **kwargs)
+    granularity, mode, backend = support
+    st = PeelExecutor(
+        granularity=granularity, mode=mode, backend=backend, window=window, chunk=64
+    ).peel(p, **kwargs)
+    _assert_states_equal(ref, st)
+    truss = np.asarray(st.trussness)
+    for g, (a, b) in zip(graphs, packed.edge_ranges):
+        assert np.array_equal(truss[a:b], trussness_numpy(g)), g.name
 
 
 # ------------------------------------------------------------------ #

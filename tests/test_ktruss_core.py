@@ -166,7 +166,7 @@ def test_bucketed_fine_matches_oracle(small_graphs):
 
 
 # ------------------------------------------------------------------ #
-# Eager window intersection at windows of one and of several blocks
+# Window intersection at windows of one and of several blocks
 # ------------------------------------------------------------------ #
 def _hub_graph(w, seed):
     """A hub whose directed row is exactly ``w`` wide, below a vertex
@@ -184,17 +184,31 @@ def _hub_graph(w, seed):
     return from_edges(w + 2, np.concatenate([hub, low, chords]), name=f"hub{w}-{seed}")
 
 
+def _support_at(variant, p, alive, window):
+    gran, mode = variant
+    if gran == "coarse":
+        return support_coarse_eager(p, alive, window=window, row_chunk=8)
+    if mode == "owner":
+        return support_fine_owner(p, alive, window=window, chunk=64)
+    return support_fine_eager(p, alive, window=window, chunk=64)
+
+
+@pytest.mark.parametrize("variant", ALL_VARIANTS, ids=["fine-eager", "fine-owner", "coarse"])
 @pytest.mark.parametrize("w", [8, 32, 64, 256, 512])
-def test_eager_intersection_matches_oracle_at_window(w):
+def test_support_matches_oracle_at_window(w, variant):
     graphs = [_hub_graph(w, seed) for seed in range(3)]
     assert all(g.max_degree() == w for g in graphs)  # the hub sets W
+    # Each support's own window rule: the owner support reads the
+    # undirected degree, the others the directed one.
+    owner = variant[1] == "owner"
+    window = max(_w(g, owner=owner) for g in graphs)
 
     # Dead lanes: a partly peeled alive mask.
     g = graphs[0]
     p = prepare_fine(g, chunk=64)
     rng = np.random.default_rng(w)
     alive_np = (rng.random(p.nnz_pad) < 0.7) & (np.asarray(p.colidx) != 0)
-    s = np.asarray(support_fine_eager(p, jnp.asarray(alive_np), window=w, chunk=64))
+    s = np.asarray(_support_at(variant, p, jnp.asarray(alive_np), window))
     live = alive_np[: g.nnz]
     assert np.array_equal(s[: g.nnz], support_numpy(g, live) * live)
     assert not s[g.nnz :].any()
@@ -206,10 +220,17 @@ def test_eager_intersection_matches_oracle_at_window(w):
         graphs, slot_n=w + 2, slot_nnz=slot_nnz, slots=4, chunk=64, layout="aligned"
     )
     alive = jnp.asarray(pp.problem.colidx != 0)
-    s = np.asarray(support_fine_eager(pp.problem, alive, window=w, chunk=64))
+    s = np.asarray(_support_at(variant, pp.problem, alive, window))
     for g, (a, b) in zip(graphs, pp.edge_ranges):
         assert np.array_equal(s[a:b], support_numpy(g)), g.name
-    exe = PeelExecutor(mode="eager", backend="xla", window=w, chunk=64)
+    if variant[0] == "coarse" and w > 256:
+        # A coarse pass costs O(n·W²), far outside its bounded-degree
+        # regime at W 512: its supports are checked above, its peel to W 256.
+        return
+    exe = PeelExecutor(
+        granularity=variant[0], mode=variant[1], backend="xla",
+        window=window, chunk=64, row_chunk=8,
+    )
     st = exe.peel(
         pp.problem,
         slot_ids=np.repeat(np.arange(4, dtype=np.int32), slot_nnz),

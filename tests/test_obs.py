@@ -373,30 +373,3 @@ def test_fake_clock_drives_trace_timestamps():
                 clock.advance(0.25)
         (ev,) = tr.events()
         assert ev["dur"] == pytest.approx(0.25e6)  # microseconds
-
-
-# ------------------------------------------------------------------ #
-# Fused megakernel: one kernel launch per truss level
-# ------------------------------------------------------------------ #
-def test_fused_peel_one_kernel_per_level(tmp_path):
-    """Chrome traces show one "peel-level" span per fused launch, the
-    `peel_fused_levels` counter ticks once per launch, and the batch
-    still costs ONE dispatch — the megakernel contract: a whole level
-    completes inside a single kernel launch."""
-    path = tmp_path / "fused_trace.json"
-    s = Session(
-        trace=str(path), backend="fine/fused/aligned", chunk=64, max_batch=2
-    )
-    s.submit(TrussQuery.decompose(rmat(6, 4, seed=2)))
-    s.flush()
-    stats = s.stats()
-    assert stats["device_dispatches"] == 1
-    levels = int(s.obs.metrics.value("peel_fused_levels"))
-    assert levels >= 1
-    events = json.loads(path.read_text())["traceEvents"]
-    level_spans = [e for e in events if e["name"] == "peel-level"]
-    # the dispatch/span-counter invariant: counter == launches == spans
-    assert len(level_spans) == levels
-    assert [e["args"]["level"] for e in level_spans] == list(range(levels))
-    # and the per-level launches all nest inside the ONE dispatch span
-    assert sum(1 for e in events if e["name"] == "dispatch") == 1

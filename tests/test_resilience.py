@@ -31,6 +31,7 @@ from repro.api import (
     Session,
     TrussError,
     TrussQuery,
+    available_backends,
     fallback_backends,
 )
 from repro.api.cache import CompileCache
@@ -213,16 +214,9 @@ def test_fallback_chain_shapes():
         BackendKey("fine", "xla", "contig"),
         BackendKey("coarse", "xla", "contig"),
     )
-    # the fused megakernel steps down to its unfused Pallas twin first,
-    # then XLA, then coarse — layout preserved at every step
-    assert fallback_backends("fine/fused/aligned") == (
-        BackendKey("fine", "pallas", "aligned"),
-        BackendKey("fine", "xla", "aligned"),
-        BackendKey("coarse", "xla", "aligned"),
-    )
     # layout is preserved down the whole chain (mesh safety)
     assert all(k.layout == "aligned" for k in fallback_backends("fine/pallas/aligned"))
-    assert all(k.layout == "aligned" for k in fallback_backends("fine/fused/aligned"))
+    assert BackendKey("fine", "fused", "aligned") not in available_backends()
     # the last resort has nowhere to fall
     assert fallback_backends("coarse/xla/contig") == ()
 
@@ -324,20 +318,17 @@ def test_compile_fault_falls_back_bit_identically():
     assert s.backend_fallbacks == 1 and s.retries == 0
 
 
-def test_poisoned_fused_compile_lands_on_xla_bit_identically():
-    """A fused megakernel whose compile is poisoned walks its chain
-    (fused -> pallas -> xla); poisoning the first two steps lands the
+def test_poisoned_pallas_compile_lands_on_xla_bit_identically():
+    """A forced Pallas kernel whose compile is poisoned takes the chain's
+    first step (pallas -> xla, same formulation and layout) and lands the
     batch on fine/xla with oracle-identical results."""
     g = tiny()
     s = Session(
-        backend="fine/fused/aligned",
+        backend="fine/pallas/aligned",
         max_batch=2,
         chunk=64,
         faults=FaultPlan(
             [
-                FaultSpec(
-                    "compile", times=1, where=(("backend", "fine/fused/aligned"),)
-                ),
                 FaultSpec(
                     "compile", times=1, where=(("backend", "fine/pallas/aligned"),)
                 ),
@@ -345,9 +336,15 @@ def test_poisoned_fused_compile_lands_on_xla_bit_identically():
         ),
         retry=FAST_RETRY,
     )
-    dec = s.solve([TrussQuery.decompose(g)])[0]
+    fut = s.submit(TrussQuery.decompose(g))
+    s.flush()
+    dec = fut.result()
+    assert fut.stats.backend == BackendKey("fine", "xla", "aligned")
     assert np.array_equal(dec.trussness, _oracle(g))
-    assert s.backend_fallbacks == 2 and s.retries == 0
+    assert s.backend_fallbacks == 1 and s.retries == 0
+    assert s.obs.metrics.value(
+        "backend_fallbacks", **{"from": "fine/pallas/aligned", "to": "fine/xla/aligned"}
+    ) == 1
 
 
 def test_poison_member_quarantined_survivors_bit_identical():
