@@ -27,6 +27,16 @@ O(W²) per task with no gather.  On a TPU v5e one gathered element costs
 as much as thousands of compares, so this beats a binary search of
 element gathers, O(W log W), at every window from 128 to 4,096 (PERF.md,
 section 6).  The owner mode still searches.
+
+For the same reason the eager mode reads its windows whole.  Both are
+rows of the adjacency (row i past the task's own position, and row κ), so
+a pass first lays the alive keys out as an ``(n+1, W)`` table, one row
+per vertex, and each chunk then reads its windows by two row gathers and
+adds its updates by two row scatter-adds into a support table of that
+shape.  Read lane by lane the same chunk costs 6·chunk·W element gathers
+and scatters, which took 98% of a pass on the chip; read by a dynamic
+slice of the lane array, XLA expands each window into a loop of slices.
+The explicit-task path (degree buckets) still reads lane by lane.
 """
 
 from __future__ import annotations
@@ -156,6 +166,11 @@ def support_fine_eager(
 ) -> jax.Array:
     """Support per directed edge via the eager triple-update (Alg. 3).
 
+    Without ``tasks`` every lane is a task, and both windows are read as
+    rows of a per-vertex table (:func:`_support_rows`).  With ``tasks`` a
+    bucket's window may be narrower than a row, so the windows are read
+    lane by lane (:func:`_support_tasks`).
+
     Args:
       p: problem arrays (``prepare_fine``).
       alive: (nnzp,) bool — surviving edges (pad lanes False).
@@ -170,25 +185,98 @@ def support_fine_eager(
     Returns:
       (nnzp,) int32 support (0 on dead/pad lanes).
     """
-    nnzp = p.nnz_pad
-    n_tasks = nnzp if tasks is None else int(tasks.shape[0])
+    n_tasks = p.nnz_pad if tasks is None else int(tasks.shape[0])
     if n_tasks % chunk:
         raise ValueError(f"tasks={n_tasks} not a multiple of chunk={chunk}")
-    w = int(window)
+    if tasks is None:
+        s = _support_rows(p, alive, window=int(window), chunk=chunk)
+        return s if s_init is None else s_init + s
+    return _support_tasks(p, alive, tasks, window=int(window), chunk=chunk, s_init=s_init)
+
+
+def _intersect(q: jax.Array, b_key: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """Broadcast compare of (chunk, W) queries with (chunk, W) row-κ keys.
+
+    Queries are 0 or 1-based vertex ids, keys a vertex id or -1, so only
+    live entries of both windows match.  Each window holds distinct ids,
+    so a query matches at most one key and the reverse.  Row κ is compared
+    in blocks of at most ``_B_BLOCK`` entries to bound one step's
+    (chunk, W, block) compare; XLA fuses each into its two reductions.
+    Returns (a_hit, b_hit): which queries and which keys matched.
+    """
+    a_hit = jnp.zeros(q.shape, bool)
+    b_hits = []
+    for k0 in range(0, b_key.shape[1], _B_BLOCK):
+        eq = q[:, :, None] == b_key[:, None, k0 : k0 + _B_BLOCK]
+        a_hit |= jnp.any(eq, axis=2)
+        b_hits.append(jnp.any(eq, axis=1))
+    return a_hit, jnp.concatenate(b_hits, axis=1)
+
+
+def _support_rows(p: FineProblem, alive: jax.Array, *, window: int, chunk: int) -> jax.Array:
+    """The eager pass over every lane, its windows read as table rows.
+
+    Lane ``e`` of row ``i`` is cell ``(i, pos(e))`` of an ``(n+1, W)``
+    table, ``pos`` its offset from the row's start.  Task ``t`` of edge
+    ``(i, κ)`` then reads its suffix window as row ``i`` past ``pos(t)``
+    and its row-κ window as row ``κ``: one row gather each per chunk.  The
+    u2 and u3 updates are one row scatter-add each into a support table of
+    the same shape, with u1 folded into u2 at the task's own cell.  A pass
+    costs one element scatter (the key table) and one element gather (the
+    support read back) of ``nnz_pad`` lanes, against 6·chunk·W element
+    reads and writes a chunk lane by lane.  Pad lanes map to no cell; the
+    cells no lane fills hold the key -1 and never match.
+    """
+    nnzp, rows, w = p.nnz_pad, p.n + 1, window
+    offs = jnp.arange(w, dtype=jnp.int32)[None, :]
+    lane = jnp.arange(nnzp, dtype=jnp.int32)
+    real = p.colidx != 0
+    i = p.edge_row
+    # Row starts from rowptr, the only way it is read (FineProblem's
+    # contract): per_chip_support rebases it to a chip's lane block.
+    pos = lane - p.rowptr[jnp.maximum(i, 1) - 1] * (i > 0)
+    cell_row = jnp.where(real, i, rows)  # pad lanes: out of range, dropped
+    key = jnp.where(alive, p.colidx, -1)
+    tab = jnp.full((rows, w), -1, jnp.int32).at[cell_row, pos].set(key, mode="drop")
+    valid = real & alive
+
+    def body(s_tab: jax.Array, xs):
+        i_c, kappa, pos_t, valid_t = xs
+        a = tab[i_c]
+        q = jnp.where((offs > pos_t[:, None]) & valid_t[:, None] & (a > 0), a, 0)
+        a_hit, b_hit = _intersect(q, tab[kappa])
+        a_hit = a_hit.astype(jnp.int32)
+        # u2 on the matched suffix cells, u1 on the task's own cell.
+        own = jnp.where(offs == pos_t[:, None], jnp.sum(a_hit, axis=1, keepdims=True), 0)
+        s_tab = s_tab.at[i_c].add(a_hit + own)
+        # u3 on the matched row-κ cells.
+        s_tab = s_tab.at[kappa].add(b_hit.astype(jnp.int32))
+        return s_tab, None
+
+    xs = tuple(x.reshape(-1, chunk) for x in (i, p.colidx, pos, valid))
+    s_tab, _ = jax.lax.scan(body, jnp.zeros((rows, w), jnp.int32), xs)
+    return s_tab.at[cell_row, pos].get(mode="fill", fill_value=0)
+
+
+def _support_tasks(
+    p: FineProblem,
+    alive: jax.Array,
+    tasks: jax.Array,
+    *,
+    window: int,
+    chunk: int,
+    s_init: jax.Array | None,
+) -> jax.Array:
+    """The eager pass over explicit tasks, its windows read lane by lane."""
+    nnzp, w = p.nnz_pad, window
     offs = jnp.arange(w, dtype=jnp.int32)[None, :]
 
     def body(s_acc: jax.Array, chunk_start: jax.Array):
-        idx = chunk_start + jnp.arange(chunk, dtype=jnp.int32)
-        if tasks is not None:
-            raw = tasks[idx]
-            skip = raw >= nnzp
-            t = jnp.minimum(raw, nnzp - 1).astype(jnp.int32)
-        else:
-            t = idx
-            skip = jnp.zeros((chunk,), bool)
+        raw = tasks[chunk_start + jnp.arange(chunk, dtype=jnp.int32)]
+        t = jnp.minimum(raw, nnzp - 1).astype(jnp.int32)
         kappa = p.colidx[t]
         i = p.edge_row[t]
-        valid_t = (kappa != 0) & alive[t] & ~skip
+        valid_t = (kappa != 0) & alive[t] & (raw < nnzp)
 
         # --- row-i suffix window (queries) -------------------------------
         a_idx = t[:, None] + 1 + offs  # global colidx positions
@@ -204,26 +292,13 @@ def support_fine_eager(
         q = jnp.where(a_alive & valid_t[:, None], a_vals, 0)
 
         # --- row-κ window, keyed for the compare -------------------------
-        # Dead and out-of-row lanes read -1, which no query (0 or a 1-based
-        # vertex id) equals, so the masks fold into the key.
+        # Dead and out-of-row lanes read -1, which no query equals.
         b_start = p.rowptr[jnp.maximum(kappa, 1) - 1] * (kappa > 0)
         b_idx_c = jnp.clip(b_start[:, None] + offs, 0, nnzp - 1)
         b_in = offs < p.deg[kappa][:, None]
         b_key = jnp.where(b_in & alive[b_idx_c], p.colidx[b_idx_c], -1)
 
-        # --- intersection: broadcast compare, no gathers -----------------
-        # Each window holds distinct ids, so a suffix entry matches at most
-        # one row-κ entry and the reverse.  Row κ is compared in blocks of
-        # at most _B_BLOCK entries to bound one step's (chunk, W, block)
-        # compare; XLA fuses each into its two reductions.
-        a_hit = jnp.zeros((chunk, w), bool)
-        b_hits = []
-        for k0 in range(0, w, _B_BLOCK):
-            eq = q[:, :, None] == b_key[:, None, k0 : k0 + _B_BLOCK]
-            a_hit |= jnp.any(eq, axis=2)
-            b_hits.append(jnp.any(eq, axis=1))
-        b_hit = jnp.concatenate(b_hits, axis=1)
-
+        a_hit, b_hit = _intersect(q, b_key)
         # u1: the task's own edge accumulates the intersection size.
         s_acc = s_acc.at[t].add(jnp.sum(a_hit, axis=1, dtype=jnp.int32))
         # u2: matched suffix entries (edges (i, m)) — scatter to row i slots.
@@ -234,7 +309,7 @@ def support_fine_eager(
         s_acc = s_acc.at[u3_tgt.reshape(-1)].add(1, mode="drop")
         return s_acc, None
 
-    starts = jnp.arange(0, n_tasks, chunk, dtype=jnp.int32)
+    starts = jnp.arange(0, int(tasks.shape[0]), chunk, dtype=jnp.int32)
     s0 = jnp.zeros(nnzp, jnp.int32) if s_init is None else s_init
     s_final, _ = jax.lax.scan(body, s0, starts)
     return s_final

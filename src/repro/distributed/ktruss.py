@@ -8,7 +8,10 @@ shard boundaries — sharding every edge-dim array over a 1-D ``"slots"``
 mesh axis gives each device a subset of whole member graphs, with no
 cross-device triangle closing.  Vertex-dim arrays (``rowptr``, ``deg``,
 ``urowptr``, ``udeg``) stay replicated: they are O(n) index metadata, tiny
-next to the O(nnz·window) intersection state.
+next to the O(nnz·window) intersection state.  The support pass's
+per-vertex window tables, ``(n+1, window)`` over the global vertex ids,
+are per device: each device fills the rows of its own vertices, and no
+task of its lanes reads another's.
 
 The fine eager support pass runs per device (:func:`per_chip_support`,
 under ``shard_map``): each device scans only its own lanes, with

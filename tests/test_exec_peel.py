@@ -271,6 +271,44 @@ def test_supports_give_identical_peel_state(support, frozen_half):
         assert np.array_equal(truss[a:b], trussness_numpy(g)), g.name
 
 
+@pytest.mark.parametrize("frozen_every", [2, 3], ids=["half", "third"])
+@pytest.mark.parametrize(
+    "graphs",
+    [
+        lambda: [rmat(6, 16, seed=s) for s in range(2)],
+        lambda: [rmat(6, 6, seed=5), barabasi(40, 4, seed=2)],
+    ],
+    ids=["kron", "rmat-barabasi"],
+)
+def test_eager_peel_with_frozen_lanes_matches_oracle(graphs, frozen_every):
+    """The eager support read from its per-vertex tables under build_peel,
+    with frozen lanes counting as alive while the threshold is inside
+    their truss: every slot decomposes to the oracle's trussness, and a
+    third slot holding the first graph with every lane dead to nothing."""
+    graphs = graphs()
+    packed, slot_ids, window = _packed_batch(graphs + [graphs[0]])
+    p = packed.problem
+    real = np.asarray(p.colidx) != 0
+    real[packed.edge_ranges[2][0] :] = False  # the third slot: all dead
+    frozen = real & (np.arange(real.shape[0]) % frozen_every == 0)
+    frozen_truss = np.zeros(real.shape[0], np.int32)
+    for g, (a, b) in zip(graphs, packed.edge_ranges):
+        frozen_truss[a:b] = np.where(frozen[a:b], trussness_numpy(g), 0)
+    st = PeelExecutor(window=window, chunk=64).peel(
+        p,
+        slot_ids=slot_ids,
+        k0=np.full(packed.slots, 3, np.int32),
+        alive0=jnp.asarray(real & ~frozen),
+        frozen=jnp.asarray(frozen),
+        frozen_truss=jnp.asarray(frozen_truss),
+    )
+    truss = np.asarray(st.trussness)
+    for g, (a, b) in zip(graphs, packed.edge_ranges):
+        assert np.array_equal(truss[a:b], trussness_numpy(g)), g.name
+    assert not truss[packed.edge_ranges[2][0] :].any()
+    assert int(st.kmax[2]) == 0
+
+
 # ------------------------------------------------------------------ #
 # Sharded executor on 8 simulated host devices == unsharded
 # ------------------------------------------------------------------ #
@@ -366,7 +404,7 @@ window = 1 << int(max(g.undirected_csr().degrees().max() for g in full) - 1).bit
 mesh = slot_mesh(4)
 
 
-def batch(graphs, frozen_every):
+def batch(graphs, frozen_every, dead_share):
     pp = pack_problems(graphs, slot_n=SLOT_N, slot_nnz=slot_nnz, slots=SLOTS,
                        chunk=CHUNK, layout="aligned")
     real = np.zeros(SLOTS * slot_nnz, bool)
@@ -377,15 +415,17 @@ def batch(graphs, frozen_every):
     frozen = np.zeros_like(real)
     if frozen_every:
         frozen = real & (np.arange(real.size) % frozen_every == 0)
-    return pp.problem, real & ~frozen, frozen, np.where(frozen, truss, 0)
+    alive = real & ~frozen & (np.random.default_rng(7).random(real.size) >= dead_share)
+    return pp.problem, alive, frozen, np.where(frozen, truss, 0)
 
 
 CASES = {
-    "ktruss3-frozen": ("eager", full, True, 5),
-    "decompose-frozen": ("eager", full, False, 5),
-    "ktruss3-empty-chips": ("eager", full[:2], True, 0),
-    "decompose-empty-chips": ("eager", full[:2], False, 0),
-    "owner-decompose-frozen": ("owner", full, False, 5),
+    "ktruss3-frozen": ("eager", full, True, 5, 0.0),
+    "decompose-frozen": ("eager", full, False, 5, 0.0),
+    "ktruss3-empty-chips": ("eager", full[:2], True, 0, 0.0),
+    "decompose-empty-chips": ("eager", full[:2], False, 0, 0.0),
+    "owner-decompose-frozen": ("owner", full, False, 5, 0.0),
+    "decompose-dead-lanes": ("eager", full, False, 0, 0.4),
 }
 executors = {
     mode: {
@@ -395,8 +435,8 @@ executors = {
     for mode in ("eager", "owner")
 }
 out = {}
-for name, (mode, graphs, single, frozen_every) in CASES.items():
-    p, alive0, frozen, frozen_truss = batch(graphs, frozen_every)
+for name, (mode, graphs, single, frozen_every, dead_share) in CASES.items():
+    p, alive0, frozen, frozen_truss = batch(graphs, frozen_every, dead_share)
     kw = dict(
         slot_ids=np.repeat(np.arange(SLOTS), slot_nnz), k0=np.full(SLOTS, 3),
         single_level=np.full(SLOTS, single), alive0=jnp.asarray(alive0),
@@ -434,6 +474,7 @@ PER_CHIP_CASES = (
     "ktruss3-empty-chips",
     "decompose-empty-chips",
     "owner-decompose-frozen",
+    "decompose-dead-lanes",
 )
 
 
@@ -464,7 +505,7 @@ def test_per_chip_support_matches_one_chip(per_chip_runs, case):
     """The support run per chip over its own lanes (fine/xla eager), and the
     GSPMD support it falls back to (owner), give one chip's peel bit for
     bit: frozen lanes, hub rows ending on a chip's last lane, whole chips of
-    empty slots."""
+    empty slots, 40% of the lanes dead from the start."""
     run = per_chip_runs[case]
     assert run["fields_differ"] == []
     assert run["support_same"] and run["support_nonzero"] > 0

@@ -18,7 +18,7 @@ from repro.core import (
     trussness_numpy,
 )
 from repro.exec import PeelExecutor
-from repro.graphs import CSRGraph, from_edges, pack_problems
+from repro.graphs import CSRGraph, from_edges, pack_problems, rmat
 
 import jax.numpy as jnp
 
@@ -239,3 +239,55 @@ def test_support_matches_oracle_at_window(w, variant):
     truss = np.asarray(st.trussness)
     for g, (a, b) in zip(graphs, pp.edge_ranges):
         assert np.array_equal(truss[a:b], trussness_numpy(g)), g.name
+
+
+# ------------------------------------------------------------------ #
+# Eager windows read as table rows: families, masks, exact windows
+# ------------------------------------------------------------------ #
+# Graph500's Kronecker initiator at its edge factor 16 (the benchmark's
+# graphs, cut to scale 6), and an R-MAT of a flatter initiator.
+_FAMILIES = {
+    "kron": lambda seed: rmat(6, 16, seed=seed),
+    "rmat": lambda seed: rmat(7, 4, a=0.45, b=0.22, c=0.22, seed=seed),
+}
+
+
+def _mask(name, real, seed):
+    if name == "full":
+        return real
+    if name == "empty":
+        return np.zeros_like(real)
+    return real & (np.random.default_rng(seed).random(real.shape[0]) < 0.6)
+
+
+@pytest.mark.parametrize("mask", ["full", "partial", "empty"])
+@pytest.mark.parametrize("family", sorted(_FAMILIES))
+def test_fine_eager_support_matches_oracle_under_masks(family, mask):
+    """The eager pass, its windows read as rows of the per-vertex table,
+    at a window exactly the widest directed row (its last lane fills the
+    table's last column): alone, and in a slot-aligned batch whose slots
+    end in pad lanes, the last slot empty."""
+    graphs = [_FAMILIES[family](seed) for seed in range(2)]
+    for g in graphs:
+        p = prepare_fine(g, chunk=64)
+        alive = _mask(mask, np.asarray(p.colidx) != 0, g.nnz)
+        s = np.asarray(
+            support_fine_eager(p, jnp.asarray(alive), window=g.max_degree(), chunk=64)
+        )
+        live = alive[: g.nnz]
+        assert np.array_equal(s[: g.nnz], support_numpy(g, live) * live), g.name
+        assert not s[g.nnz :].any()
+
+    slot_nnz = 64 * -(-max(g.nnz for g in graphs) // 64) + 64
+    pp = pack_problems(
+        graphs, slot_n=max(g.n for g in graphs), slot_nnz=slot_nnz, slots=3,
+        chunk=64, layout="aligned",
+    )
+    alive = _mask(mask, np.asarray(pp.problem.colidx) != 0, 7)
+    window = max(g.max_degree() for g in graphs)
+    s = np.asarray(support_fine_eager(pp.problem, jnp.asarray(alive), window=window, chunk=64))
+    owned = np.zeros(s.shape[0], bool)
+    for g, (a, b) in zip(graphs, pp.edge_ranges):
+        assert np.array_equal(s[a:b], support_numpy(g, alive[a:b]) * alive[a:b]), g.name
+        owned[a:b] = True
+    assert not s[~owned].any()

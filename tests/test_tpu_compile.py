@@ -75,6 +75,17 @@ def _peel_program(granularity, mode, window):
     return build_peel(support)
 
 
+def _support_program(one_chip, bucket, slots):
+    """The compiled text of the fine eager peel for ``slots`` slots of
+    ``bucket`` on one described chip."""
+    n_pad, nnz_pad, window = bucket
+    args = peel_arg_shapes(n=slots * n_pad, nnz_pad=slots * nnz_pad, slots=slots)
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), args
+    )
+    return _peel_program("fine", "eager", window).lower(*args).compile().as_text()
+
+
 @pytest.mark.parametrize(
     "granularity,mode,bucket",
     [
@@ -104,12 +115,7 @@ def test_fine_eager_support_intersects_without_search_gathers(one_chip, bucket, 
     # The window intersection is a broadcast compare: the only gathers left
     # in the support pass read the windows and the chunk's lanes, none is
     # the binary search's take_along_axis.
-    n_pad, nnz_pad, window = bucket
-    args = peel_arg_shapes(n=slots * n_pad, nnz_pad=slots * nnz_pad, slots=slots)
-    args = jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), args
-    )
-    text = _peel_program("fine", "eager", window).lower(*args).compile().as_text()
+    text = _support_program(one_chip, bucket, slots)
     gathers = [
         line for line in text.splitlines() if " gather(" in line and "/support/" in line
     ]
@@ -194,3 +200,53 @@ def test_one_chip_peel_is_the_plain_build(one_chip):
     plain = _peel_program("fine", "eager", window).lower(*args).compile().as_text()
     assert _without_source_locations(text) == _without_source_locations(plain)
     assert _support_scan_bounds(text) == [nnz_pad // 256]
+
+
+def _moves(text):
+    """Each gather and scatter of a compiled program as (kind, elements
+    moved, window): a gather's output size and its ``slice_sizes``, a
+    scatter's update size and its ``update_window_dims``."""
+    shapes = {
+        name: [int(d) for d in dims.split(",") if d]
+        for name, dims in re.findall(r"%(\S+) = \w+\[([\d,]*)\]", text)
+    }
+    moves = []
+    for dims, sizes in re.findall(r"= \w+\[([\d,]*)\]\S* gather\(.*slice_sizes=\{([\d,]*)\}", text):
+        out = [int(d) for d in dims.split(",") if d]
+        moves.append(("gather", out, [int(s) for s in sizes.split(",")]))
+    for updates, window in re.findall(
+        r" scatter\(%[^,]+, %[^,]+, %([^,)]+)\), update_window_dims=\{([\d,]*)\}", text
+    ):
+        moves.append(("scatter", shapes[updates], [int(d) for d in window.split(",") if d]))
+    return moves
+
+
+@pytest.mark.parametrize("program", ["kron8", "kron7-b8", "mesh"])
+def test_fine_eager_support_reads_windows_as_table_rows(one_chip, mesh_cell_peel, program):
+    # Each chunk reads its two windows as two row gathers of (chunk, W)
+    # from the per-vertex table and updates the support table by two row
+    # scatter-adds.  No op of the program moves chunk×W single elements:
+    # the element gathers and scatters left (the key table, the support
+    # read back, the prune's per-slot sums) each move at most the chip's
+    # nnz_pad lanes.  The program holds two loops, the peel's and the
+    # support's chunk scan, so no dynamic-slice loop reads a window.
+    if program == "mesh":
+        bucket, slots = KRON8_BUCKET, MESH_SLOTS // 4  # a chip's own slots
+        text = mesh_cell_peel.as_text()
+    else:
+        bucket, slots = {"kron8": (KRON8_BUCKET, 1), "kron7-b8": (KRON7_BUCKET, 8)}[program]
+        text = _support_program(one_chip, bucket, slots)
+    _n_pad, nnz_pad, window = bucket
+    lanes = slots * nnz_pad
+    chunk = 256
+    assert lanes < chunk * window
+    moves = _moves(text)
+    rows = [m for m in moves if m[0] == "gather" and m[1] == [chunk, window]]
+    assert [m[2] for m in rows] == [[1, window]] * 2
+    row_scatters = [m for m in moves if m[0] == "scatter" and m[2] == [1]]
+    assert [m[1] for m in row_scatters] == [[chunk, window]] * 2
+    for kind, shape, window_dims in moves:
+        element = set(window_dims) == {1} if kind == "gather" else not window_dims
+        if element:
+            assert int(np.prod(shape)) <= lanes, (kind, shape)
+    assert len(re.findall(r" while\(", text)) == 2
